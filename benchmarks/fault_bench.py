@@ -272,6 +272,8 @@ def main(argv=None) -> int:
     ap.add_argument("--mutations", type=int, default=40,
                     help="ops per WAL-overhead measurement")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     wal = wal_overhead(args.mutations)
     rec = recovery_curve()

@@ -1,501 +1,81 @@
-"""Benchmark runner: exercise the paper workloads through the Session API
-and record the perf trajectory.
+"""Benchmark runner: every benchmark phase, each in its own process.
 
-Writes ``BENCH_2.json`` (repo root, uploaded as a CI artifact): per-workload
-ops/sec + latency percentiles, all measured through ``blend.connect`` /
-``session.query`` / ``session.sql`` / ``DiscoveryEngine.serve_many`` — the
-same code paths users hit.  Also writes ``BENCH_3.json`` with the LiveLake
-mutation workloads (``mutate/add_table_p50``, ``mutate/compact``,
-``snapshot/load_vs_rebuild``) and ``BENCH_4.json`` with the semantic
-query-cache workloads: repeat-query hits vs cold serving (acceptance:
->= 10x p50), partial hits over a shared subtree, unique-query miss
-overhead, batched warm serving, and the mutation-invalidation cycle.
-``BENCH_5.json`` records the fused-execution workloads: deep-DAG plan
-latency fused vs unfused (acceptance: >= 3x p50, launches <= n_kinds + 1)
-and 12-request ``serve_many`` throughput (>= 2x).  ``BENCH_6.json`` records
-the sharded-lake workloads (benchmarks/sharded_bench.py, run as a
-subprocess under 8 forced host devices): per-device probe throughput and
-``serve_many`` req/s vs shard count 1/2/4/8, weak-scaling efficiency, and
-the merge-epilogue overhead (acceptance: >= 3x probe throughput at 8
-shards vs 1).  ``BENCH_7.json`` records the serving front-tier workloads
-(benchmarks/serving_bench.py, run as a subprocess so its paced open-loop
-replays get a quiet interpreter): goodput and p50/p99 vs offered load
-under a seeded Zipf/bursty trace, batch-occupancy histograms, shed rate
-at overload, and the query+mutation barrier scenario (acceptance: batched
-goodput >= 3x single-request serving with shedding engaged and bounded
-queues at the heaviest offered load).  The same subprocess also writes
-``BENCH_8.json``: the observability cost/coverage benchmark — queue-wait
-p50/p99 per offered load, tier throughput with instrumentation disabled /
-metrics-only / metrics+tracing (acceptance: disabled path costs <= 2% vs
-the BENCH_7 tier baseline from the same run), and per-request trace span
-coverage.  ``BENCH_9.json`` records the approximate-discovery workloads
-(benchmarks/sketch_bench.py, its own process): approx-vs-exact p50 and
-recall@10 per seeker kind at 1k/10k (CI smoke) or 1k/10k/100k columns
-(``--full``), plus the escalation-rate/recall curve vs epsilon
-(acceptance: >= 3x p50 at <= 5% recall loss on the largest scale).
-``BENCH_10.json`` records the durability workloads (benchmarks/
-fault_bench.py, its own process): WAL-on vs WAL-off mutation throughput
-(acceptance: best durable mode within ~15%), crash-recovery time vs WAL
-length with bit-identity checks, the injected-fault serving sweep (zero
-wrong results, degraded flagged, deadlines enforced), and trace replay
-with client retries.
+This process never imports JAX, so each phase owns the accelerator while it
+runs (a parent holding the chip would leave its children none).  Phases run
+in order; the runner exits non-zero if any of them failed.
 
-    PYTHONPATH=src python benchmarks/run_all.py [--out PATH] [--full]
+* ``core`` — ``benchmarks/core_bench.py``: the Session-API workloads,
+  ``BENCH_2.json`` to ``BENCH_5.json``;
+* ``sharded`` — ``benchmarks/sharded_bench.py``: ``BENCH_6.json``, on the
+  devices the process is given (``XLA_FLAGS=
+  --xla_force_host_platform_device_count=8`` gives a CPU run eight);
+* ``serving`` — ``benchmarks/serving_bench.py``: ``BENCH_7.json`` and
+  ``BENCH_8.json`` (the CI-sized smoke sweep unless ``--full``);
+* ``sketch`` — ``benchmarks/sketch_bench.py``: ``BENCH_9.json``;
+* ``fault`` — ``benchmarks/fault_bench.py``: ``BENCH_10.json``.
 
-``--full`` additionally runs the paper-table benchmark suites
-(benchmarks/run.py) and folds their per-table JSON into the payload.
+``--full`` first runs the paper-table suites (``benchmarks/run.py``) and
+folds their per-table JSON into ``BENCH_2.json``.
+
+    python benchmarks/run_all.py [--out PATH] [--full] [--iters N]
 """
 from __future__ import annotations
 
 import argparse
-import json
-import platform
+import os
+import subprocess
 import sys
-import time
 from pathlib import Path
 
 REPO_ROOT = Path(__file__).resolve().parents[1]
-for p in (REPO_ROOT, REPO_ROOT / "src"):       # runnable as a plain script
-    if str(p) not in sys.path:
-        sys.path.insert(0, str(p))
-
-import numpy as np
-
-import blend
-from repro.core.cost_model import train_cost_model
-from repro.core.lake import synthetic_lake
-from repro.serve.engine import DiscoveryEngine
+BENCH = REPO_ROOT / "benchmarks"
 
 
-def _stats(seconds: list) -> dict:
-    a = np.asarray(seconds)
-    return {
-        "iters": int(a.size),
-        "ops_per_sec": float(a.size / a.sum()) if a.sum() else 0.0,
-        "mean_ms": float(a.mean() * 1e3),
-        "p50_ms": float(np.percentile(a, 50) * 1e3),
-        "p95_ms": float(np.percentile(a, 95) * 1e3),
-    }
+def phases(out_dir: Path, full: bool, iters: int) -> list:
+    py = sys.executable
+
+    def out(n):
+        return str(out_dir / f"BENCH_{n}.json")
+
+    return ([("paper_tables", [py, "-m", "benchmarks.run"])] if full else []) \
+        + [("core", [py, str(BENCH / "core_bench.py"), "--out", out(2),
+                     "--iters", str(iters)]
+            + (["--paper-tables"] if full else [])),
+           ("sharded", [py, str(BENCH / "sharded_bench.py"), "--out", out(6),
+                        "--iters", str(iters)]),
+           ("serving", [py, str(BENCH / "serving_bench.py"), "--out", out(7),
+                        "--out8", out(8)] + ([] if full else ["--smoke"])),
+           ("sketch", [py, str(BENCH / "sketch_bench.py"), "--out", out(9),
+                       "--iters", str(iters), "--scales",
+                       "1000,10000,100000" if full else "1000,10000"]),
+           ("fault", [py, str(BENCH / "fault_bench.py"), "--out", out(10),
+                      "--mutations", "40" if full else "24"])]
 
 
-def _measure(fn, warmup: int = 2, iters: int = 10) -> dict:
-    for _ in range(warmup):
-        fn()
-    seconds = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        fn()
-        seconds.append(time.perf_counter() - t0)
-    return _stats(seconds)
-
-
-def _requests(lake, rng, n: int):
-    from examples.serve_discovery import build_request
-    kinds = ["imputation", "union", "enrichment"]
-    return [build_request(lake, rng, kinds[i % 3]) for i in range(n)]
-
-
-def live_workloads(lake, iters: int = 5) -> dict:
-    """LiveLake mutation + persistence workloads (BENCH_3)."""
-    import tempfile
-
-    from repro.core.index import build_index
-    from repro.core.lake import Table
-
-    rng = np.random.default_rng(3)
-
-    def fresh_table(i, rows=40):
-        return Table(f"bench_add_{i}",
-                     [[f"tok_{int(x)}" for x in rng.integers(0, 1500, rows)],
-                      [f"tok_{int(x)}" for x in rng.integers(0, 1500, rows)],
-                      [float(x) for x in np.round(rng.normal(0, 5, rows), 3)]])
-
-    workloads = {}
-
-    # baseline: what a mutation would cost without LiveLake
-    rebuild_s = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        build_index(lake)
-        rebuild_s.append(time.perf_counter() - t0)
-    rebuild_p50 = float(np.percentile(rebuild_s, 50))
-
-    # mutate/add_table_p50: one 40-row table in, one delta segment out
-    session = blend.connect(lake, live=True)
-    session.query(blend.kw(["tok_1"], k=5))        # resident + warm
-    k = [0]
-
-    def add_drop():
-        tid = session.add_table(fresh_table(k[0]))
-        k[0] += 1
-        session.drop_table(tid)                    # keep state stable
-
-    stats = _measure(add_drop, warmup=2, iters=iters * 4)
-    stats["rebuild_p50_ms"] = rebuild_p50 * 1e3
-    stats["speedup_vs_rebuild"] = rebuild_p50 / (stats["p50_ms"] / 1e3)
-    workloads["mutate/add_table_p50"] = stats
-
-    # mutate/compact: merge a burst of 8 deltas back into the base
-    # (auto-compact off so the timed call does the whole merge)
-    from repro.store import LiveLake
-    compact_s = []
-    for it in range(max(iters // 2, 3)):
-        s2 = blend.connect(LiveLake(lake, auto_compact=False), live=True)
-        for j in range(8):
-            s2.add_table(fresh_table(100 + it * 8 + j))
-        t0 = time.perf_counter()
-        s2.compact()
-        compact_s.append(time.perf_counter() - t0)
-    workloads["mutate/compact"] = _stats(compact_s)
-
-    # snapshot/load_vs_rebuild: restart path vs indexing from scratch
-    with tempfile.TemporaryDirectory() as td:
-        path = Path(td) / "bench.snap"
-        session.snapshot(path)
-        load_s = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            blend.restore(path)
-            load_s.append(time.perf_counter() - t0)
-        stats = _stats(load_s)
-        stats["rebuild_p50_ms"] = rebuild_p50 * 1e3
-        stats["speedup_vs_rebuild"] = \
-            rebuild_p50 / float(np.percentile(load_s, 50))
-        workloads["snapshot/load_vs_rebuild"] = stats
-    return workloads
-
-
-def cache_workloads(lake, iters: int = 10) -> dict:
-    """Semantic query-cache serving workloads (BENCH_4)."""
-    from repro.core.lake import Table
-    from repro.serve.engine import DiscoveryEngine
-
-    rng = np.random.default_rng(4)
-    t = lake.tables[11]
-    rows = list(range(8))
-    impute = (blend.mc([(t.columns[0][r], t.columns[1][r]) for r in rows],
-                       k=40)
-              & blend.sc([t.columns[0][r] for r in rows], k=40)).top(10)
-    shared_sc = blend.sc([t.columns[0][r] for r in rows], k=40)
-    union_vote = blend.counter(
-        *[blend.sc(list(t.columns[c]), k=60) for c in range(3)], k=10)
-
-    def fresh_table(i, rows=40):
-        return Table(f"bench_cache_{i}",
-                     [[f"tok_{int(x)}" for x in rng.integers(0, 1500, rows)],
-                      [f"tok_{int(x)}" for x in rng.integers(0, 1500, rows)],
-                      [float(x) for x in np.round(rng.normal(0, 5, rows), 3)]])
-
-    workloads = {}
-    cold = blend.connect(lake)
-    cached = blend.connect(lake, cache=True)
-
-    # repeat-query: the identical request served over and over — the
-    # acceptance workload (hit p50 vs cold serving p50, >= 10x)
-    cold_stats = _measure(lambda: cold.query(impute).ids, iters=iters)
-    hit_stats = _measure(lambda: cached.query(impute).ids, iters=iters * 4)
-    hit_stats["cold_p50_ms"] = cold_stats["p50_ms"]
-    hit_stats["speedup_vs_cold"] = cold_stats["p50_ms"] / hit_stats["p50_ms"]
-    workloads["cache/repeat_hit"] = hit_stats
-
-    # partial hit: a stream of distinct queries all sharing one hot subtree
-    # (the subplan cache carries the shared seeker, the cold sibling runs)
-    def partial_stream(session, i):
-        q = (shared_sc | blend.kw([t.columns[1][i[0] % 30]], k=40)).top(10)
-        i[0] += 1
-        return session.query(q).ids
-
-    ic, iw = [0], [0]
-    cold_partial = _measure(lambda: partial_stream(cold, ic), iters=iters)
-    cached.query(shared_sc)                       # warm the shared subtree
-    part_stats = _measure(lambda: partial_stream(cached, iw),
-                          iters=iters)
-    part_stats["cold_p50_ms"] = cold_partial["p50_ms"]
-    part_stats["speedup_vs_cold"] = \
-        cold_partial["p50_ms"] / part_stats["p50_ms"]
-    workloads["cache/partial_hit"] = part_stats
-
-    # miss overhead: every query unique — the fingerprint + insert cost the
-    # cache adds on a workload it can never serve
-    def unique_stream(session, i):
-        base = int(i[0] * 8) % 1400
-        i[0] += 1
-        return session.query(
-            blend.sc([f"tok_{base + j}" for j in range(8)], k=40)).ids
-
-    iu, iv = [0], [500]
-    cold_uni = _measure(lambda: unique_stream(cold, iu), iters=iters)
-    miss_stats = _measure(lambda: unique_stream(cached, iv), iters=iters)
-    miss_stats["cold_p50_ms"] = cold_uni["p50_ms"]
-    miss_stats["overhead_vs_cold"] = \
-        miss_stats["p50_ms"] / cold_uni["p50_ms"]
-    workloads["cache/miss_overhead"] = miss_stats
-
-    # batched warm serving: serve_many over a fully-warmed request set —
-    # cache hits pay no drain share, so the whole batch collapses to lookups
-    engine = DiscoveryEngine(lake, cache=True)
-    reqs = _requests(lake, rng, 12)
-    engine.serve_many(reqs)                       # warm jit + cache
-    warm_stats = _measure(lambda: engine.serve_many(reqs), warmup=1,
-                          iters=max(iters // 2, 3))
-    warm_stats["requests_per_sec"] = warm_stats["ops_per_sec"] * len(reqs)
-    warm_stats["hit_ratio"] = (engine.session.cache.hits /
-                               max(engine.session.cache.hits
-                                   + engine.session.cache.misses
-                                   + engine.session.cache.partial, 1))
-    workloads["cache/batch12_warm"] = warm_stats
-
-    # mutation-invalidation: add -> serve (recompute) -> drop -> serve; the
-    # epoch wipe forces cold work, so this bounds the cost of staying fresh
-    # (bit-identity to a cold rebuild is asserted in tests/test_query_cache)
-    live_sess = blend.connect(lake, live=True, cache=True)
-    pool = [impute, union_vote]
-    for q in pool:
-        live_sess.query(q)
-    k = [0]
-
-    def mutate_cycle():
-        tid = live_sess.add_table(fresh_table(k[0]))
-        k[0] += 1
-        for q in pool:
-            live_sess.query(q).ids
-        live_sess.drop_table(tid)
-        for q in pool:
-            live_sess.query(q).ids
-
-    mut_stats = _measure(mutate_cycle, warmup=1, iters=max(iters // 2, 3))
-    mut_stats["invalidations"] = live_sess.cache.invalidations
-    mut_stats["cache_stats"] = live_sess.cache.stats()
-    workloads["cache/mutation_invalidation"] = mut_stats
-    return workloads
-
-
-def fused_workloads(lake, iters: int = 10) -> dict:
-    """Fused-execution workloads (BENCH_5): deep-DAG plan latency fused vs
-    unfused, batched serve_many throughput, and the launch counts that
-    explain the difference.  Cold here means cold *query cache* (none is
-    attached) with a warm jit cache — the steady serving state."""
-    from examples.fused_serving import deep_query
-
-    session = blend.connect(lake)
-    engine = DiscoveryEngine(lake, session=session)
-    q = deep_query(lake)
-
-    workloads = {}
-    unf = _measure(lambda: session.query(q).ids, iters=iters)
-    fus = _measure(lambda: session.query(q, fused=True).ids, iters=iters)
-    n_unf = session.query(q).info.launches
-    n_fus = session.query(q, fused=True).info.launches
-    assert session.query(q, fused=True).ids == session.query(q).ids
-    unf["launches"] = n_unf
-    fus["launches"] = n_fus
-    fus["speedup_vs_unfused"] = unf["p50_ms"] / fus["p50_ms"]
-    workloads["fused/deep_dag_unfused"] = unf
-    workloads["fused/deep_dag_fused"] = fus
-
-    reqs = [deep_query(lake, tab) for tab in range(12)]
-    engine.serve_many(reqs)                       # warm every program
-    engine.serve_many(reqs, fused=True)
-    unf = _measure(lambda: engine.serve_many(reqs), warmup=1,
-                   iters=max(iters // 2, 3))
-    fus = _measure(lambda: engine.serve_many(reqs, fused=True), warmup=1,
-                   iters=max(iters // 2, 3))
-    resp = engine.serve_many(reqs, fused=True)
-    unf["requests_per_sec"] = unf["ops_per_sec"] * len(reqs)
-    fus["requests_per_sec"] = fus["ops_per_sec"] * len(reqs)
-    fus["speedup_vs_unfused"] = unf["p50_ms"] / fus["p50_ms"]
-    fus["launches_per_request"] = max(r.launches for r in resp)
-    workloads["serve/batch12_deep_unfused"] = unf
-    workloads["serve/batch12_deep_fused"] = fus
-    return workloads
-
-
-def main(out_path: Path, full: bool = False, iters: int = 10) -> dict:
-    rng = np.random.default_rng(7)
-    lake = synthetic_lake(n_tables=200, rows=40, vocab=1500, seed=1)
-    session = blend.connect(lake)
-    t = lake.tables[11]
-    rows = list(range(8))
-
-    impute = (blend.mc([(t.columns[0][r], t.columns[1][r]) for r in rows],
-                       k=40)
-              & blend.sc([t.columns[0][r] for r in rows], k=40)).top(10)
-    union_vote = blend.counter(
-        *[blend.sc(list(t.columns[c]), k=60) for c in range(3)], k=10)
-    negative = (blend.mc([(t.columns[0][r], t.columns[1][r])
-                          for r in rows[:5]], k=40)
-                - blend.mc([(t.columns[0][6], t.columns[1][7])], k=40)).top(10)
-    enrich_sql = (blend.kw([t.columns[0][0], t.columns[1][1]], k=10)
-                  | blend.corr([t.columns[0][r] for r in rows],
-                               list(map(float, rows)), k=10)).top(20).to_sql()
-
-    workloads = {}
-
-    workloads["query/imputation_fluent"] = _measure(
-        lambda: session.query(impute).ids, iters=iters)
-    workloads["query/imputation_noopt"] = _measure(
-        lambda: session.query(impute, optimize=False).ids, iters=iters)
-    workloads["query/union_counter"] = _measure(
-        lambda: session.query(union_vote).ids, iters=iters)
-    workloads["query/negative_examples"] = _measure(
-        lambda: session.query(negative).ids, iters=iters)
-    workloads["sql/enrichment"] = _measure(
-        lambda: session.sql(enrich_sql).ids, iters=iters)
-    workloads["compile/parse_rewrite_lower"] = _measure(
-        lambda: session.compile(enrich_sql), iters=max(iters * 20, 100))
-
-    # batched serving through the engine (12 heterogeneous requests/batch),
-    # reusing the session so the warm jit cache carries over
-    engine = DiscoveryEngine(lake, session=session)
-    engine.cost_model = train_cost_model(session.executor, lake, n_samples=10)
-    reqs = _requests(lake, rng, 12)
-    engine.serve_many(reqs)               # warm every capacity bucket
-    batch_stats = _measure(lambda: engine.serve_many(reqs),
-                           warmup=1, iters=max(iters // 2, 3))
-    batch_stats["requests_per_sec"] = \
-        batch_stats["ops_per_sec"] * len(reqs)
-    workloads["serve/batch12_mixed"] = batch_stats
-
-    payload = {
-        "bench": "BENCH_2",
-        "platform": platform.platform(),
-        "python": platform.python_version(),
-        "lake": lake.stats(),
-        "workloads": workloads,
-    }
-
-    if full:
-        import subprocess
-        import sys
-        subprocess.run([sys.executable, str(REPO_ROOT / "benchmarks/run.py")],
-                       check=False)
-        results_dir = REPO_ROOT / "benchmarks" / "results"
-        payload["paper_tables"] = {
-            p.stem: json.loads(p.read_text())
-            for p in sorted(results_dir.glob("*.json"))}
-
-    out_path.write_text(json.dumps(payload, indent=2, sort_keys=True) + "\n")
-    print(f"wrote {out_path}")
-
-    live = live_workloads(lake, iters=max(iters // 2, 5))
-    live_payload = {
-        "bench": "BENCH_3",
-        "platform": platform.platform(),
-        "python": platform.python_version(),
-        "lake": lake.stats(),
-        "workloads": live,
-    }
-    live_path = out_path.parent / "BENCH_3.json"
-    live_path.write_text(
-        json.dumps(live_payload, indent=2, sort_keys=True) + "\n")
-    print(f"wrote {live_path}")
-
-    cache = cache_workloads(lake, iters=iters)
-    cache_payload = {
-        "bench": "BENCH_4",
-        "platform": platform.platform(),
-        "python": platform.python_version(),
-        "lake": lake.stats(),
-        "workloads": cache,
-    }
-    cache_path = out_path.parent / "BENCH_4.json"
-    cache_path.write_text(
-        json.dumps(cache_payload, indent=2, sort_keys=True) + "\n")
-    print(f"wrote {cache_path}")
-
-    fused = fused_workloads(lake, iters=iters)
-    fused_payload = {
-        "bench": "BENCH_5",
-        "platform": platform.platform(),
-        "python": platform.python_version(),
-        "lake": lake.stats(),
-        "workloads": fused,
-    }
-    fused_path = out_path.parent / "BENCH_5.json"
-    fused_path.write_text(
-        json.dumps(fused_payload, indent=2, sort_keys=True) + "\n")
-    print(f"wrote {fused_path}")
-
-    # sharded-lake workloads need their own process: jax locks the host
-    # device count at first init, and BENCH_6 runs on 8 forced CPU devices
-    import os
-    import subprocess
-    sharded_path = out_path.parent / "BENCH_6.json"
-    r = subprocess.run(
-        [sys.executable, str(REPO_ROOT / "benchmarks/sharded_bench.py"),
-         "--out", str(sharded_path), "--iters", str(iters)],
-        env={**os.environ,
-             "XLA_FLAGS": "--xla_force_host_platform_device_count=8"},
-        check=False)
-    if r.returncode == 0:
-        print(f"wrote {sharded_path}")
-    else:
-        print(f"sharded bench failed (exit {r.returncode}); "
-              f"skipping {sharded_path}")
-
-    # serving front tier: also its own process — the load sweep replays
-    # paced traces against a dispatcher thread, and a fresh interpreter
-    # keeps this runner's jit caches and GC pauses out of its latencies.
-    # The full sweep (5 offered-load levels, warm-until-stable per level)
-    # takes minutes; without --full run the CI-sized smoke sweep.
-    serving_path = out_path.parent / "BENCH_7.json"
-    obs_path = out_path.parent / "BENCH_8.json"
-    r = subprocess.run(
-        [sys.executable, str(REPO_ROOT / "benchmarks/serving_bench.py"),
-         "--out", str(serving_path), "--out8", str(obs_path)]
-        + ([] if full else ["--smoke"]),
-        check=False)
-    if r.returncode == 0:
-        print(f"wrote {serving_path}")
-        print(f"wrote {obs_path}")
-    else:
-        print(f"serving bench failed (exit {r.returncode}); "
-              f"skipping {serving_path}")
-
-    # approximate discovery: own process so the scale lakes (up to 100k
-    # columns under --full) are built and freed outside this runner's heap.
-    sketch_path = out_path.parent / "BENCH_9.json"
-    r = subprocess.run(
-        [sys.executable, str(REPO_ROOT / "benchmarks/sketch_bench.py"),
-         "--out", str(sketch_path), "--iters", str(iters),
-         "--scales", "1000,10000,100000" if full else "1000,10000"],
-        check=False)
-    if r.returncode == 0:
-        print(f"wrote {sketch_path}")
-    else:
-        print(f"sketch bench failed (exit {r.returncode}); "
-              f"skipping {sketch_path}")
-
-    # durability and fault tolerance: own process — the WAL overhead
-    # measurement times fsync-bound mutation acks and wants a quiet heap
-    fault_path = out_path.parent / "BENCH_10.json"
-    r = subprocess.run(
-        [sys.executable, str(REPO_ROOT / "benchmarks/fault_bench.py"),
-         "--out", str(fault_path),
-         "--mutations", "40" if full else "24"],
-        check=False)
-    if r.returncode == 0:
-        print(f"wrote {fault_path}")
-    else:
-        print(f"fault bench failed (exit {r.returncode}); "
-              f"skipping {fault_path}")
-
-    for name, s in {**workloads, **live, **cache, **fused}.items():
-        extra = "".join(
-            f" ({s[key]:.0f}x vs {key.rsplit('_', 1)[-1]})"
-            for key in ("speedup_vs_rebuild", "speedup_vs_cold",
-                        "speedup_vs_unfused")
-            if key in s)
-        print(f"{name:32s} {s['ops_per_sec']:10.1f} ops/s "
-              f"p50={s['p50_ms']:.2f}ms p95={s['p95_ms']:.2f}ms{extra}")
-    return payload
+def main(out_path: Path, full: bool = False, iters: int = 10) -> int:
+    path = os.pathsep.join([str(REPO_ROOT), str(REPO_ROOT / "src")]
+                           + [p for p in [os.environ.get("PYTHONPATH")] if p])
+    env = {**os.environ, "PYTHONPATH": path}
+    failed = []
+    for name, cmd in phases(out_path.parent, full, iters):
+        print(f"[run_all] {name}: {' '.join(cmd)}", flush=True)
+        rc = subprocess.run(cmd, cwd=REPO_ROOT, env=env).returncode
+        if rc:
+            print(f"[run_all] {name} FAILED (exit {rc})", flush=True)
+            failed.append(name)
+    if failed:
+        print(f"[run_all] failed phases: {', '.join(failed)}")
+        return 1
+    print("[run_all] all phases passed")
+    return 0
 
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--out", type=Path, default=REPO_ROOT / "BENCH_2.json")
+    ap.add_argument("--out", type=Path, default=REPO_ROOT / "BENCH_2.json",
+                    help="BENCH_2.json path; the other files go beside it")
     ap.add_argument("--full", action="store_true",
                     help="also run the paper-table suites (benchmarks/run.py)")
     ap.add_argument("--iters", type=int, default=10)
     args = ap.parse_args()
-    main(args.out, full=args.full, iters=args.iters)
+    sys.exit(main(args.out, full=args.full, iters=args.iters))

@@ -396,6 +396,8 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="small lake / short traces for CI")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     b7 = main(args.out, seed=args.seed, duration_s=args.duration,
               smoke=args.smoke)
     main_obs(args.out8, seed=args.seed, duration_s=args.duration,

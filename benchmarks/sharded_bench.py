@@ -2,12 +2,14 @@
 ``serve_many`` request rate vs shard count, weak-scaling efficiency, and the
 merge-epilogue overhead.
 
-Forces 8 host CPU devices (must run in its own process — jax locks the
-device count at first init; ``run_all.py`` launches it as a subprocess).
+Runs on the devices the process is given; shards beyond the device count
+wrap onto devices round-robin (``dist/shard.py``).  On a CPU host, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before the process
+starts to give it eight devices.
 
-The host has far fewer cores than shards, so shard programs that would run
-concurrently on a real mesh execute serially here.  The benchmark therefore
-times each shard's fused probe program **in isolation** — that is the
+A CPU host has far fewer cores than shards, so shard programs that would
+run concurrently on a real mesh execute serially there.  The benchmark
+therefore times each shard's fused probe program **in isolation** — that is the
 per-device serving cost of the MPMD deployment — and reports
 
     modeled_parallel_p50 = max(per-shard p50) + merge epilogue
@@ -22,10 +24,6 @@ the global one), not a simulation artifact.
     PYTHONPATH=src python benchmarks/sharded_bench.py [--out PATH]
 """
 from __future__ import annotations
-
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import argparse
 import json
@@ -183,7 +181,6 @@ def weak_scaling_workloads(iters: int) -> dict:
 
 def main(out_path: Path, iters: int = 9) -> dict:
     import jax
-    assert len(jax.devices()) == 8, jax.devices()
     probe, accept = probe_workloads(iters)
     serve = serve_workloads(iters)
     weak = weak_scaling_workloads(iters)
@@ -192,6 +189,8 @@ def main(out_path: Path, iters: int = 9) -> dict:
         "platform": platform.platform(),
         "python": platform.python_version(),
         "devices": len(jax.devices()),
+        "device": {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind},
         "workloads": {**probe, **serve, **weak},
         "acceptance": accept,
     }
@@ -210,4 +209,6 @@ if __name__ == "__main__":
     ap.add_argument("--out", type=Path, default=REPO_ROOT / "BENCH_6.json")
     ap.add_argument("--iters", type=int, default=9)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(args.out, iters=args.iters)

@@ -232,5 +232,7 @@ if __name__ == "__main__":
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--scales", type=str, default="1000,10000,100000")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(args.out, iters=args.iters,
          scales=[int(s) for s in args.scales.split(",")])

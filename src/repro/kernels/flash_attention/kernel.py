@@ -26,8 +26,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block, causal, sq, skv):
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.ds(j * kv_block, kv_block), pl.ds(0, d)))
-        v = pl.load(v_ref, (pl.ds(j * kv_block, kv_block), pl.ds(0, d)))
+        k = k_ref[pl.ds(j * kv_block, kv_block), :]
+        v = v_ref[pl.ds(j * kv_block, kv_block), :]
         s = q @ k.astype(jnp.float32).T * scale            # [Tq, Tk]
         if causal:
             qpos = qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, kv_block), 0)

@@ -13,13 +13,15 @@ from jax.experimental import pallas as pl
 
 
 def _qcr_kernel(quad_ref, qbit_ref, valid_ref, out_ref):
-    quad = quad_ref[...]
-    qbit = qbit_ref[...]
+    # v5e has no int8 vector compare (and Mosaic folds a widening to int32
+    # back into one): compare the small integers exactly in f32
+    quad = quad_ref[...].astype(jnp.float32)
+    qbit = qbit_ref[...].astype(jnp.float32)
     valid = valid_ref[...]
     v = valid.astype(jnp.float32)
     agree = jnp.where(valid & (quad == qbit), 1.0, 0.0)
-    n = jnp.sum(v, axis=1)
-    a = jnp.sum(agree, axis=1)
+    n = jnp.sum(v, axis=1, keepdims=True)
+    a = jnp.sum(agree, axis=1, keepdims=True)
     qcr = jnp.abs(2.0 * a - n) / jnp.maximum(n, 1.0)
     out_ref[...] = jnp.where(n >= 3, qcr, 0.0)
 
@@ -60,7 +62,9 @@ def qcr_score(quadrants, qbits, valid, *, g_block=128, interpret=False):
         _qcr_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((g_block, h), lambda i: (i, 0))] * 3,
-        out_specs=pl.BlockSpec((g_block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((g,), jnp.float32),
+        # a [G, 1] column: a rank-1 [g_block] block would not match the
+        # 1024-element tiling XLA gives a 1-D f32 array on the TPU
+        out_specs=pl.BlockSpec((g_block, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((g, 1), jnp.float32),
         interpret=interpret,
-    )(quadrants, qbits, valid)
+    )(quadrants, qbits, valid)[:, 0]

@@ -2,6 +2,9 @@
 
 Tiled elementwise bitwise AND + compare: each grid step streams a [T_blk,
 N_blk] tile through VMEM (the MC seeker's bloom pruning stage, MATE-style).
+Query digests enter as ``[T, 1]`` columns: a rank-1 block must be a multiple
+of 128 on the TPU, while a 2-D ``[T_blk, 1]`` block only needs T_blk to be a
+multiple of 8 (its lane dimension spans the whole array).
 """
 import functools
 
@@ -13,20 +16,20 @@ from jax.experimental import pallas as pl
 def _sk_kernel(sk_lo_ref, sk_hi_ref, q_lo_ref, q_hi_ref, out_ref):
     sk_lo = sk_lo_ref[...]                    # [N_blk]
     sk_hi = sk_hi_ref[...]
-    q_lo = q_lo_ref[...]                      # [T_blk]
+    q_lo = q_lo_ref[...]                      # [T_blk, 1]
     q_hi = q_hi_ref[...]
-    lo_ok = (sk_lo[None, :] & q_lo[:, None]) == q_lo[:, None]
-    hi_ok = (sk_hi[None, :] & q_hi[:, None]) == q_hi[:, None]
+    lo_ok = (sk_lo[None, :] & q_lo) == q_lo
+    hi_ok = (sk_hi[None, :] & q_hi) == q_hi
     out_ref[...] = lo_ok & hi_ok
 
 
 def _sk_rows_kernel(sk_lo_ref, sk_hi_ref, q_lo_ref, q_hi_ref, out_ref):
     sk_lo = sk_lo_ref[...]                    # [T_blk, M]
     sk_hi = sk_hi_ref[...]
-    q_lo = q_lo_ref[...]                      # [T_blk]
+    q_lo = q_lo_ref[...]                      # [T_blk, 1]
     q_hi = q_hi_ref[...]
-    lo_ok = (sk_lo & q_lo[:, None]) == q_lo[:, None]
-    hi_ok = (sk_hi & q_hi[:, None]) == q_hi[:, None]
+    lo_ok = (sk_lo & q_lo) == q_lo
+    hi_ok = (sk_hi & q_hi) == q_hi
     out_ref[...] = lo_ok & hi_ok
 
 
@@ -45,13 +48,13 @@ def superkey_filter_rows(sk_lo, sk_hi, q_lo, q_hi, *, t_block=8,
         in_specs=[
             pl.BlockSpec((t_block, m), lambda i: (i, 0)),
             pl.BlockSpec((t_block, m), lambda i: (i, 0)),
-            pl.BlockSpec((t_block,), lambda i: (i,)),
-            pl.BlockSpec((t_block,), lambda i: (i,)),
+            pl.BlockSpec((t_block, 1), lambda i: (i, 0)),
+            pl.BlockSpec((t_block, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((t_block, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, m), jnp.bool_),
         interpret=interpret,
-    )(sk_lo, sk_hi, q_lo, q_hi)
+    )(sk_lo, sk_hi, q_lo[:, None], q_hi[:, None])
 
 
 @functools.partial(jax.jit, static_argnames=("t_block", "n_block", "interpret"))
@@ -67,10 +70,10 @@ def superkey_filter(sk_lo, sk_hi, q_lo, q_hi, *, t_block=8, n_block=1024,
         in_specs=[
             pl.BlockSpec((n_block,), lambda i, j: (j,)),
             pl.BlockSpec((n_block,), lambda i, j: (j,)),
-            pl.BlockSpec((t_block,), lambda i, j: (i,)),
-            pl.BlockSpec((t_block,), lambda i, j: (i,)),
+            pl.BlockSpec((t_block, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((t_block, 1), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((t_block, n_block), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, n), jnp.bool_),
         interpret=interpret,
-    )(sk_lo, sk_hi, q_lo, q_hi)
+    )(sk_lo, sk_hi, q_lo[:, None], q_hi[:, None])

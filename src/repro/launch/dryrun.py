@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# NOTE: the two lines above MUST stay first — jax locks the device count on
-# first init.  (This also forces the docstring below to be a plain comment.)
+# NOTE: the lines above MUST stay first — jax locks the platform and the
+# device count on first init.  The dry-run compiles on 512 host devices and
+# never takes an accelerator; its per-cell children inherit both settings.
+# (This also forces the docstring below to be a plain comment.)
 
 # Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 #

@@ -7,29 +7,21 @@ importing jax (see launch/dryrun.py, first two lines).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (Auto) only where the
-    installed jax supports it."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axes)
+AUTO2 = (AxisType.Auto,) * 2
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2x16x16 = 512 chips across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke tests / benchmarks."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
 
 
 def make_mesh_for(devices: int, *, model_parallel: int = 16):
@@ -37,4 +29,5 @@ def make_mesh_for(devices: int, *, model_parallel: int = 16):
     model = min(model_parallel, devices)
     while devices % model:
         model -= 1
-    return compat_make_mesh((devices // model, model), ("data", "model"))
+    return jax.make_mesh((devices // model, model), ("data", "model"),
+                         axis_types=AUTO2)

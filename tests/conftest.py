@@ -1,54 +1,5 @@
-import sys
-import types
-
 import numpy as np
 import pytest
-
-
-def _install_hypothesis_stub():
-    """Let the suite collect on images without hypothesis installed.
-
-    Property tests import ``given/settings/strategies`` at module scope; with
-    this stub they collect normally and individually skip (importorskip-style
-    guard, but per-test instead of per-module so the non-property tests in the
-    same files still run).
-    """
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-    class _Strategy:
-        def map(self, f):
-            return self
-
-        def filter(self, f):
-            return self
-
-        def flatmap(self, f):
-            return self
-
-    st = types.ModuleType("hypothesis.strategies")
-    st.__getattr__ = lambda name: (lambda *a, **k: _Strategy())
-
-    def given(*a, **k):
-        return pytest.mark.skip(reason="hypothesis not installed "
-                                       "(see requirements-dev.txt)")
-
-    def settings(*a, **k):
-        return lambda fn: fn
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = st
-    mod.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st
-
-
-_install_hypothesis_stub()
 
 
 @pytest.fixture(scope="session")

@@ -14,10 +14,10 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType
     from repro.dist.pipeline import pipeline_apply, bubble_fraction
 
-    mesh = compat_make_mesh((4,), ("stage",))
+    mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
     n_stages, n_micro, mb, d = 4, 8, 2, 16
     rng = np.random.default_rng(0)
     ws = jnp.asarray(rng.normal(0, 0.5, (n_stages, d, d)), jnp.float32)
