@@ -154,6 +154,13 @@ class Executor:
         self.cap_ladder = tuple(sorted(rungs))
         self._hash_cache: dict = {}
         self._hash_cache_max = 1 << 20
+        #: running totals that the flight recorder's ``hash`` and
+        #: ``optimize`` spans read as differences: values looked up in the
+        #: hash memo, those not in it (``hash_value`` ran), and planner
+        #: statistics ``host_counts`` calls
+        self.n_hashed = 0
+        self.n_hash_misses = 0
+        self.n_stat_scans = 0
         self._in_plan = False
         #: approximate tier: dense sketch packs, memoized per (epoch,
         #: geometry) — rebuilt lazily like the MatchEngine, never mid-query
@@ -205,7 +212,9 @@ class Executor:
             if h is None:
                 h = hash_value(v)
                 cache[v] = h
+                self.n_hash_misses += 1
             out[i] = h
+        self.n_hashed += len(vals)
         return out
 
     def _hashed(self, values) -> np.ndarray:
@@ -228,6 +237,7 @@ class Executor:
         """Planner-statistics counts: on a live store, tombstoned postings
         are excluded (they contribute no results, only probe-window slots),
         so seeker ranking reflects the live lake."""
+        self.n_stat_scans += 1
         if hasattr(self.index, "segments"):
             return self.index.host_counts(h, live_only=True)
         return self.index.host_counts(h)
@@ -512,10 +522,6 @@ class Executor:
         ep = optimize_plan(plan, self.seeker_stats, cost_model) if optimize \
             else None
         memo: dict[str, comb.ResultSet] = {}
-        # synchronized-timing mode (repro.obs.set_sync_timing): per-node
-        # timings measure device compute, not async-dispatch enqueue —
-        # each node blocks before its clock read, serializing the pipeline
-        sync_time = obs.sync_timing()
 
         def timed_seeker(name, spec, allowed=None):
             t0 = time.perf_counter()
@@ -530,8 +536,6 @@ class Executor:
                 info.cached_nodes.append(name)
             else:
                 rs = self.run_seeker(spec, allowed=allowed, sync=sync)
-                if sync_time and not sync:
-                    jax.block_until_ready(rs.scores)
                 info.seeker_runs += 1
                 info.launches += self._last_launches
                 info.overflow_parts.append(self._last_overflow)
@@ -569,8 +573,6 @@ class Executor:
                         b = eval_node(node.deps[1])
                     t0 = time.perf_counter()
                     rs = comb.difference(a, b, k)
-                    if sync_time:
-                        jax.block_until_ready(rs.scores)
                     info.node_seconds[name] = time.perf_counter() - t0
                     info.order.append(name)
                     info.launches += 1
@@ -585,8 +587,6 @@ class Executor:
                         rs = comb.counter(deps, k)
                     else:
                         raise ValueError(kind)
-                    if sync_time:
-                        jax.block_until_ready(rs.scores)
                     info.node_seconds[name] = time.perf_counter() - t0
                     info.order.append(name)
                     info.launches += 1
@@ -624,8 +624,6 @@ class Executor:
                 results.append(eval_node(dep))
         t0 = time.perf_counter()
         rs = comb.intersect(results, combiner_node.spec.k)
-        if obs.sync_timing():
-            jax.block_until_ready(rs.scores)
         info.node_seconds[combiner_node.name] = time.perf_counter() - t0
         info.order.append(combiner_node.name)
         info.launches += 1

@@ -214,60 +214,74 @@ def _hash_tasks(ex, tasks):
     """Hash every pending seeker's query values (through the executor's
     memoized value-hash cache) and pick every capacity from one batched
     ``host_counts`` lookup over the concatenated hash arrays."""
-    reqs = []
-    for t in tasks:
-        spec = t.spec
-        if spec.kind in ("SC", "KW"):
-            t.h = ex._hashed(spec.values)
-            reqs.append(t.h)
-        elif spec.kind == "C":
-            pairs = list(dict.fromkeys(zip(spec.values, spec.target)))
-            t.h = ex._hash_many([p[0] for p in pairs])
-            tgt = np.array([float(p[1]) for p in pairs])
-            t.qbit = (tgt >= tgt.mean()).astype(np.int8) if len(tgt) \
-                else np.zeros(0, np.int8)
-            reqs.append(t.h)
-        else:                                       # MC
-            values = list(dict.fromkeys(spec.values))
-            t.nt = len(values)
-            n_cols = spec.n_cols
-            t.th = np.stack([ex._hash_many([v[c] for v in values])
-                             for c in range(n_cols)], axis=1) if values \
-                else np.zeros((0, n_cols), np.uint32)
-            qks = np.array([row_superkey(t.th[i], np.zeros(n_cols, np.int64))
-                            for i in range(t.nt)], np.uint64)
-            t.qk_lo, t.qk_hi = split_u64(qks)
-            reqs.append(t.th.reshape(-1))
     if not tasks:
         return
-    lens = np.array([len(r) for r in reqs], np.int64)
-    offs = np.concatenate([[0], np.cumsum(lens)])
-    all_h = np.concatenate(reqs) if offs[-1] else np.zeros(0, np.uint32)
-    n_shards = getattr(ex, "n_shards", 0)
-    if n_shards:
-        # per-shard counts in the same ONE batched lookup: global capacities
-        # (and the MC initiator-column pick) come from the summed counts —
-        # identical to a 1-shard run — while each shard's probe window sizes
-        # to its own counts (a shard only holds its own tables' postings)
-        per = ex.index.host_counts(all_h, per_shard=True)
-        counts = per.sum(axis=0)
-    else:
-        counts = ex.index.host_counts(all_h)
-    for i, t in enumerate(tasks):
-        c = counts[offs[i]:offs[i + 1]]
-        if t.spec.kind == "MC":
-            cm = c.reshape(t.nt, t.spec.n_cols) if t.nt \
-                else np.zeros((0, t.spec.n_cols), np.int64)
-            t.init_col = np.argmin(cm, axis=1).astype(np.int32) if t.nt \
-                else np.zeros(0, np.int32)
-            t.m_cap = ex._quantize_cap(int(cm.max(initial=1)))
-        else:
-            t.m_cap = ex._quantize_cap(int(c.max(initial=1)))
+    rec = otrace.current()
+    reqs = []
+    with rec.span("hash") as sp:
+        hashed0, misses0 = ex.n_hashed, ex.n_hash_misses
+        superkeys = 0
+        for t in tasks:
+            spec = t.spec
+            if spec.kind in ("SC", "KW"):
+                t.h = ex._hashed(spec.values)
+                reqs.append(t.h)
+            elif spec.kind == "C":
+                pairs = list(dict.fromkeys(zip(spec.values, spec.target)))
+                t.h = ex._hash_many([p[0] for p in pairs])
+                tgt = np.array([float(p[1]) for p in pairs])
+                t.qbit = (tgt >= tgt.mean()).astype(np.int8) if len(tgt) \
+                    else np.zeros(0, np.int8)
+                reqs.append(t.h)
+            else:                                       # MC
+                values = list(dict.fromkeys(spec.values))
+                t.nt = len(values)
+                n_cols = spec.n_cols
+                t.th = np.stack([ex._hash_many([v[c] for v in values])
+                                 for c in range(n_cols)], axis=1) \
+                    if values else np.zeros((0, n_cols), np.uint32)
+                qks = np.array([row_superkey(t.th[i],
+                                             np.zeros(n_cols, np.int64))
+                                for i in range(t.nt)], np.uint64)
+                t.qk_lo, t.qk_hi = split_u64(qks)
+                reqs.append(t.th.reshape(-1))
+                superkeys += t.nt
+        if rec.enabled:
+            sp.set("values", ex.n_hashed - hashed0)
+            sp.set("misses", ex.n_hash_misses - misses0)
+            sp.set("superkeys", superkeys)
+    with rec.span("capacity") as sp:
+        lens = np.array([len(r) for r in reqs], np.int64)
+        offs = np.concatenate([[0], np.cumsum(lens)])
+        all_h = np.concatenate(reqs) if offs[-1] else np.zeros(0, np.uint32)
+        if rec.enabled:
+            sp.set("hashes", len(all_h))
+        n_shards = getattr(ex, "n_shards", 0)
         if n_shards:
-            t.shard_caps = tuple(
-                ex._quantize_cap(int(per[s, offs[i]:offs[i + 1]]
-                                     .max(initial=1)))
-                for s in range(n_shards))
+            # per-shard counts in the same ONE batched lookup: global
+            # capacities (and the MC initiator-column pick) come from the
+            # summed counts — identical to a 1-shard run — while each
+            # shard's probe window sizes to its own counts (a shard only
+            # holds its own tables' postings)
+            per = ex.index.host_counts(all_h, per_shard=True)
+            counts = per.sum(axis=0)
+        else:
+            counts = ex.index.host_counts(all_h)
+        for i, t in enumerate(tasks):
+            c = counts[offs[i]:offs[i + 1]]
+            if t.spec.kind == "MC":
+                cm = c.reshape(t.nt, t.spec.n_cols) if t.nt \
+                    else np.zeros((0, t.spec.n_cols), np.int64)
+                t.init_col = np.argmin(cm, axis=1).astype(np.int32) \
+                    if t.nt else np.zeros(0, np.int32)
+                t.m_cap = ex._quantize_cap(int(cm.max(initial=1)))
+            else:
+                t.m_cap = ex._quantize_cap(int(c.max(initial=1)))
+            if n_shards:
+                t.shard_caps = tuple(
+                    ex._quantize_cap(int(per[s, offs[i]:offs[i + 1]]
+                                         .max(initial=1)))
+                    for s in range(n_shards))
 
 
 # --------------------------------------------------------------------------
@@ -387,30 +401,19 @@ def _launch_group(ex, key, tasks, failed=None):
     engines = getattr(ex, "engines", None)
     rec = otrace.current()
     mreg = obs.registry()
-    sync_time = obs.sync_timing()
     if engines is None:
         caps = np.zeros(width, np.int32)
         m_cap = fill_caps(caps, None)
         with rec.span("shard:0", m_cap=m_cap, seekers=len(tasks)):
-            t0 = time.perf_counter()
-            sc, ov = dispatch(ex.engine, caps, m_cap)
-            if sync_time:
-                jax.block_until_ready(sc)
-            mreg.histogram("shard.probe_seconds.0").observe(
-                time.perf_counter() - t0)
-        return sc, ov
+            return dispatch(ex.engine, caps, m_cap)
     scores, ovf = [], []
-    shard_s = []
     for s, eng in enumerate(engines):
         caps = np.zeros(width, np.int32)
         m_cap = fill_caps(caps, s)
         with rec.span(f"shard:{s}", m_cap=m_cap, seekers=len(tasks)):
-            t0 = time.perf_counter()
             try:
                 faults.checkpoint(f"shard.probe.{s}")
                 sc, ov = dispatch(eng, caps, m_cap)
-                if sync_time:
-                    jax.block_until_ready(sc)
             except Exception:                        # noqa: BLE001
                 # InjectedCrash (BaseException) deliberately passes through:
                 # a simulated kill -9 must not be absorbed as a shard retry
@@ -419,8 +422,6 @@ def _launch_group(ex, key, tasks, failed=None):
                     eng = ex.reset_shard(s)
                     faults.checkpoint(f"shard.probe.{s}")
                     sc, ov = dispatch(eng, caps, m_cap)
-                    if sync_time:
-                        jax.block_until_ready(sc)
                     mreg.counter("shard.retries").inc()
                 except Exception:                    # noqa: BLE001
                     # rebuilt engine failed too: drop the shard from the
@@ -430,19 +431,10 @@ def _launch_group(ex, key, tasks, failed=None):
                         failed.add(s)
                     sc = jnp.zeros((nsp, ex.n_tables), jnp.float32)
                     ov = jnp.zeros(nsp, jnp.int32)
-            dt = time.perf_counter() - t0
-        shard_s.append(dt)
-        mreg.histogram(f"shard.probe_seconds.{s}").observe(dt)
         # stage results on the merge device so the single DAG program
         # consumes them without implicit cross-device transfers
         scores.append(jax.device_put(sc, ex.merge_device))
         ovf.append(jax.device_put(ov, ex.merge_device))
-    # shard skew for this launch: slowest / mean probe time (1.0 = level).
-    # Only meaningful under synchronized timing — async it measures
-    # enqueue skew, which is still a leading indicator of a hot shard.
-    mean_s = sum(shard_s) / len(shard_s)
-    if mean_s > 0:
-        mreg.gauge("shard.imbalance").set(max(shard_s) / mean_s)
     return tuple(scores), tuple(ovf)
 
 
@@ -550,10 +542,28 @@ def run_fused(ex, plans, optimize=True, cost_model=None, cache=None):
     path; returns [(ResultSet, ExecInfo)] aligned with ``plans``.  The
     caller (Executor.run / Executor.run_many) owns engine refresh and the
     final drain."""
-    eps = [optimize_plan(p, ex.seeker_stats, cost_model) if optimize
-           else None for p in plans]
-    progs = [_compile_plan(p, optimize, e, cache, i)
-             for i, (p, e) in enumerate(zip(plans, eps))]
+    rec = otrace.current()
+    mreg = obs.registry()
+    with rec.span("optimize") as sp:
+        stats0 = (ex.n_stat_scans, ex.n_hashed, ex.n_hash_misses)
+        eps = [optimize_plan(p, ex.seeker_stats, cost_model) if optimize
+               else None for p in plans]
+        if rec.enabled:
+            scans = ex.n_stat_scans - stats0[0]
+            sp.set("seekers", sum(len(g.seekers) for e in eps if e is not None
+                                  for g in e.groups.values()))
+            sp.set("stats_scans", scans)
+            # on a live store each scan passes over the alive flag of every
+            # posting (``host_counts(live_only=True)``)
+            sp.set("stats_postings", scans * ex.index.n_postings
+                   if hasattr(ex.index, "segments") else 0)
+            # seeker_stats hashes through the same memo as the ``hash``
+            # span, and before it: its lookups and misses are counted here
+            sp.set("hash_values", ex.n_hashed - stats0[1])
+            sp.set("hash_misses", ex.n_hash_misses - stats0[2])
+    with rec.span("lower", plans=len(plans)):
+        progs = [_compile_plan(p, optimize, e, cache, i)
+                 for i, (p, e) in enumerate(zip(plans, eps))]
 
     tasks = [t for pr in progs for t in pr.tasks]
     # identical seekers (same frozen spec — e.g. a hot subtree shared
@@ -573,26 +583,19 @@ def run_fused(ex, plans, optimize=True, cost_model=None, cache=None):
     group_out: dict[tuple, tuple] = {}
     launch_seconds: dict[tuple, float] = {}
     failed_shards: set = set()
-    rec = otrace.current()
-    mreg = obs.registry()
     for key in sorted(groups):
         kind_name = "/".join(str(p) for p in key)
-        # compile-vs-execute split: a launch that bumped TRACE_COUNTS paid
-        # a jit trace+compile; steady-state launches must land in
-        # exec.probe_seconds only (retrace-freedom made observable)
+        # a launch that bumped TRACE_COUNTS paid a jit trace+compile:
+        # exec.compiles and the span's ``compiled`` show which step did
         tr0 = sum(seek.TRACE_COUNTS.values())
         t0 = time.perf_counter()
         with rec.span("probe:" + kind_name, seekers=len(groups[key])) as sp:
             group_out[key] = _launch_group(ex, key, groups[key],
                                            failed=failed_shards)
-        dt = time.perf_counter() - t0
-        launch_seconds[key] = dt
+        launch_seconds[key] = time.perf_counter() - t0
         if sum(seek.TRACE_COUNTS.values()) > tr0:
             sp.set("compiled", True)
             mreg.counter("exec.compiles").inc()
-            mreg.histogram("exec.compile_seconds").observe(dt)
-        else:
-            mreg.histogram("exec.probe_seconds").observe(dt)
     group_plans: dict[tuple, set] = {}
     for t in tasks:                    # dupes adopt their head's placement
         t.group_key = t.head.group_key
@@ -619,15 +622,10 @@ def run_fused(ex, plans, optimize=True, cost_model=None, cache=None):
         t0 = time.perf_counter()
         with rec.span("merge", instrs=len(pr.instrs)) as sp:
             regs = _run_dag(gs, rows, cs, cm, prog=tuple(pr.instrs))
-            if obs.sync_timing():
-                jax.block_until_ready(regs[pr.out_reg][0])
         dag_s = time.perf_counter() - t0
         if sum(seek.TRACE_COUNTS.values()) > tr0:
             sp.set("compiled", True)
             mreg.counter("exec.compiles").inc()
-            mreg.histogram("exec.compile_seconds").observe(dag_s)
-        else:
-            mreg.histogram("exec.dag_seconds").observe(dag_s)
 
         info = ExecInfo(optimized=optimize)
         info.order = pr.order

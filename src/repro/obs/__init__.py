@@ -1,6 +1,6 @@
 """Unified observability for the serving stack: metrics + span tracing.
 
-One switch, three surfaces::
+One switch, two surfaces::
 
     from repro import obs
 
@@ -18,16 +18,12 @@ One switch, three surfaces::
   into a per-request flight recorder exportable as Chrome trace-event JSON
   (``server.dump_trace``).  Tracing works with metrics disabled and vice
   versa.
-* **synchronized timing** (:func:`set_sync_timing`) — opt-in accuracy mode
-  for the executor's per-node timings.  JAX dispatch is asynchronous, so a
-  default timing measures *enqueue* cost, not device compute: a seeker that
-  launches in 40us and computes for 4ms reports 40us.  With sync timing on,
-  the executor calls ``block_until_ready`` after each seeker / fused group
-  / DAG program before reading the clock, so ``ExecInfo.node_seconds`` and
-  the trace spans measure real compute — at the price of serializing
-  dispatch (pipelining across nodes and batched requests is lost, so
-  end-to-end latency degrades; use it in benchmarks and offline traces,
-  never in production serving).  Results are bit-identical either way.
+
+Device time comes from a ``jax.profiler`` trace, never from a host clock:
+JAX dispatch is asynchronous, so a host timing around a launch measures its
+enqueue.  While a recorder is enabled each of its spans also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so in a profile the
+spans annotate the device ops on the profiler's own clock.
 """
 from __future__ import annotations
 
@@ -40,28 +36,22 @@ from repro.obs.trace import (NULL_RECORDER, Recorder, Span,  # noqa: F401
                              chrome_trace, dump_chrome, recording)
 
 _registry = NULL_REGISTRY
-_sync_timing = False
 
 
 def enable(registry: MetricsRegistry | None = None, *,
-           sync_timing: bool | None = None,
            now=time.perf_counter) -> MetricsRegistry:
     """Install (and return) the process-local registry.  A fresh registry
-    is created unless one is passed; ``sync_timing`` optionally flips the
-    synchronized-timing mode in the same call."""
+    is created unless one is passed."""
     global _registry
     _registry = registry if registry is not None \
         else MetricsRegistry(now=now)
-    if sync_timing is not None:
-        set_sync_timing(sync_timing)
     return _registry
 
 
 def disable():
-    """Back to the no-op singleton (also clears sync timing)."""
+    """Back to the no-op singleton."""
     global _registry
     _registry = NULL_REGISTRY
-    set_sync_timing(False)
 
 
 def enabled() -> bool:
@@ -73,13 +63,3 @@ def registry():
     called.  Instrumented code calls this unconditionally."""
     return _registry
 
-
-def set_sync_timing(flag: bool):
-    """Opt in/out of synchronized per-node timing (see module docstring:
-    accurate device timings, serialized dispatch)."""
-    global _sync_timing
-    _sync_timing = bool(flag)
-
-
-def sync_timing() -> bool:
-    return _sync_timing

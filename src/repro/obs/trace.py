@@ -12,18 +12,31 @@ upstream is recording.
 
 The **flight recorder** view: ``DiscoveryServer(trace=True)`` keeps a ring
 buffer of per-request span trees (``DiscoveryResponse.trace`` carries each
-request's own root), covering submit -> queue wait -> batch formation ->
-epoch pin -> per-kind fused dispatch -> per-shard probe -> cross-shard
-merge -> drain -> host transfer.  ``server.dump_trace(path)`` exports the
-buffer as Chrome trace-event JSON (:func:`chrome_trace`) loadable in
-Perfetto / ``chrome://tracing``.
+request's own root), covering submit -> queue wait -> batch formation
+(``form``) -> epoch pin -> ``execute``: plan compile or memo lookup
+(``plan``), optimizer statistics (``optimize``), lowering to DAG programs
+(``lower``), value hashing (``hash``), the capacity lookup (``capacity``),
+per-kind fused dispatch (``probe:*``) -> per-shard probe (``shard:*``) ->
+cross-shard merge (``merge``) -> ``drain`` -> host ``transfer``.  Counts
+ride on the spans as attributes (``hash.misses``, ``optimize.stats_scans``
+and the like), computed only while a recorder is enabled.
+``server.dump_trace(path)`` exports the buffer as Chrome trace-event JSON
+(:func:`chrome_trace`) loadable in Perfetto / ``chrome://tracing``.
 
 Tracing is observation only: no span ever synchronizes the device, so
-enabling it changes no ids and no scores (parity-tested).  Span *durations*
-on the dispatch path therefore measure host-side enqueue time unless
-synchronized timing is opted into (``repro.obs.set_sync_timing`` — see the
-tradeoff note there); the span *tree* is contiguous wall-clock either way,
+enabling it changes no ids and no scores (parity-tested).  Span durations on
+the dispatch path are host time (enqueue, hashing, statistics), not device
+time.  Device time comes from a ``jax.profiler`` trace: while a recorder is
+enabled every :meth:`Recorder.span` also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so the spans land on the
+host thread's line of the profiler's ``.xplane.pb``, on the profiler's
+clock, beside the device ops and the host's own dispatch and transfer
+events.  The span *tree* is contiguous wall-clock on the recorder's clock,
 which is what makes queue + batch sum to end-to-end latency.
+
+While a traced server runs, a ``gc.callbacks`` hook (:func:`gc_spans`)
+records each garbage collection as a ``gc`` span under whatever span is
+open on the collecting thread.
 
 Clocks are injectable (``Recorder(now=...)``) so nesting/ordering tests run
 on a fake clock with exact expected timestamps.
@@ -32,7 +45,9 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import json
+import threading
 import time
 from dataclasses import dataclass, field
 
@@ -89,9 +104,13 @@ class Recorder:
     enabled = True
 
     def __init__(self, now=time.perf_counter):
+        from jax.profiler import TraceAnnotation
+
         self.now = now
         self.roots: list = []
         self._stack: list = []
+        self._annotation = TraceAnnotation
+        self._gc_t0: float | None = None
 
     def _attach(self, span: Span):
         if self._stack:
@@ -101,19 +120,21 @@ class Recorder:
 
     @contextlib.contextmanager
     def span(self, name: str, tid: str | None = None, **attrs):
-        s = Span(name=name, t0=self.now(), attrs=attrs, tid=tid)
-        self._attach(s)
-        self._stack.append(s)
-        try:
-            yield s
-        finally:
-            self._stack.pop()
-            s.t1 = self.now()
+        with self._annotation(name):
+            s = Span(name=name, t0=self.now(), attrs=attrs, tid=tid)
+            self._attach(s)
+            self._stack.append(s)
+            try:
+                yield s
+            finally:
+                self._stack.pop()
+                s.t1 = self.now()
 
     def record(self, name: str, t0: float, t1: float,
                tid: str | None = None, **attrs) -> Span:
         """Attach one pre-measured interval (e.g. queue wait, whose start
-        predates the recorder) under the currently open span."""
+        predates the recorder) under the currently open span.  Nothing goes
+        to the profiler: the interval has already passed."""
         s = Span(name=name, t0=t0, t1=t1, attrs=attrs, tid=tid)
         self._attach(s)
         return s
@@ -188,6 +209,42 @@ def recording(recorder):
         yield recorder
     finally:
         _ACTIVE.reset(token)
+
+
+# ---------------------------------------------------------------------------
+# garbage-collection spans
+# ---------------------------------------------------------------------------
+
+_gc_lock = threading.Lock()
+_gc_users = 0
+
+
+def _gc_callback(phase, info):
+    rec = _ACTIVE.get()
+    if not rec.enabled:
+        return
+    if phase == "start":
+        rec._gc_t0 = rec.now()
+    elif rec._gc_t0 is not None:
+        rec.record("gc", rec._gc_t0, rec.now(),
+                   generation=info["generation"])
+        rec._gc_t0 = None
+
+
+def gc_spans(on: bool):
+    """Install (``on``) or release one user of the process-wide
+    ``gc.callbacks`` hook that records a ``gc`` span (attribute
+    ``generation``) under the span open on the thread that collected.
+    Threads that are not recording pay one contextvar read per collection."""
+    global _gc_users
+    with _gc_lock:
+        _gc_users += 1 if on else -1
+        hooked = _gc_callback in gc.callbacks
+        if _gc_users > 0 and not hooked:
+            gc.callbacks.append(_gc_callback)
+        elif _gc_users <= 0 and hooked:
+            _gc_users = 0
+            gc.callbacks.remove(_gc_callback)
 
 
 # ---------------------------------------------------------------------------

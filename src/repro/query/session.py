@@ -23,6 +23,7 @@ from repro.core.index import build_index
 from repro.core.optimizer import optimize as optimize_plan
 from repro.core.plan import Plan
 from repro.core.sketch import ApproxParams
+from repro.obs import trace as otrace
 from repro.query import logical as L
 from repro.query.lower import lower
 from repro.query.parse import parse
@@ -417,7 +418,6 @@ class Session:
         cross-served with exact entries."""
         from repro import obs
         from repro.core import sketch as sk
-        from repro.obs import trace as otrace
 
         t0 = time.perf_counter()
         plan = compiled.plan
@@ -539,19 +539,32 @@ class Session:
             cache.begin(self.executor.index, self._cache_config())
         results: list = [None] * len(queries)
         pending: list = []                     # (index, Compiled, result key)
-        for i, q in enumerate(queries):
-            t0 = time.perf_counter()
-            comp = q if isinstance(q, Compiled) else self.compile(q, top=top)
-            if cache is None:
-                pending.append((i, comp, None, time.perf_counter() - t0))
-                continue
-            rkey = cache.result_key(comp.plan, optimize)
-            entry = cache.get_result(rkey)
-            if entry is not None:
-                results[i] = self._hit_result(entry, comp, sync,
-                                              time.perf_counter() - t0)
-            else:
-                pending.append((i, comp, rkey, time.perf_counter() - t0))
+        rec = otrace.current()
+        with rec.span("plan", requests=len(queries)) as sp:
+            # compiles the plan memo (or the cache's plan level) will serve
+            plans = self.cache.plans if cache is not None \
+                else self._plan_memo
+            plan_hits = 0
+            for i, q in enumerate(queries):
+                t0 = time.perf_counter()
+                if rec.enabled and isinstance(q, (str, L.Expr)) \
+                        and (q, top) in plans:
+                    plan_hits += 1
+                comp = q if isinstance(q, Compiled) \
+                    else self.compile(q, top=top)
+                if cache is None:
+                    pending.append((i, comp, None, time.perf_counter() - t0))
+                    continue
+                rkey = cache.result_key(comp.plan, optimize)
+                entry = cache.get_result(rkey)
+                if entry is not None:
+                    results[i] = self._hit_result(entry, comp, sync,
+                                                  time.perf_counter() - t0)
+                else:
+                    pending.append((i, comp, rkey,
+                                    time.perf_counter() - t0))
+            if rec.enabled:
+                sp.set("plan_hits", plan_hits)
         if not pending:
             return results
         if not fused:

@@ -53,7 +53,7 @@ from repro import obs
 from repro.errors import DeadlineExceeded, Overloaded
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_RECORDER, Recorder, Span, dump_chrome, \
-    recording
+    gc_spans, recording
 from repro.serve.batching import (BATCH, INTERACTIVE, SHED_RATE_LIMIT,
                                   BatchFormer, Barrier, Batch, LaneConfig,
                                   RateLimiter)
@@ -96,7 +96,9 @@ class DiscoveryServer:
     works.  ``trace=True`` turns on the per-request flight recorder: every
     response carries its span tree (``DiscoveryResponse.trace``), the last
     ``trace_capacity`` request trees are retained, and
-    :meth:`dump_trace` exports them as Chrome trace-event JSON."""
+    :meth:`dump_trace` exports them as Chrome trace-event JSON.  While a
+    traced server runs, garbage collections on the dispatcher show as
+    ``gc`` spans under the span they interrupted."""
 
     def __init__(self, engine, *, max_batch: int = 16,
                  interactive_window_s: float = 0.002,
@@ -137,6 +139,10 @@ class DiscoveryServer:
         self._trace = trace
         #: flight recorder: span trees of the most recent requests
         self._flight: deque = deque(maxlen=trace_capacity)
+        #: traced only: when the dispatcher last finished a piece of work
+        #: (batch, expiry or barrier) — the earliest a ``form`` span starts
+        self._free_s = float("-inf")
+        self._gc_hooked = False
         # pre-bound hot-path instruments (one dict lookup saved per submit)
         self._m_submitted = self.metrics.counter("server.submitted")
         self._thread: threading.Thread | None = None
@@ -148,6 +154,9 @@ class DiscoveryServer:
         if self._thread is not None and self._thread.is_alive():
             return self
         self._stopping = False
+        if self._trace and not self._gc_hooked:
+            gc_spans(True)
+            self._gc_hooked = True
         self._thread = threading.Thread(target=self._loop,
                                         name="discovery-server", daemon=True)
         self._thread.start()
@@ -170,6 +179,9 @@ class DiscoveryServer:
             self._cond.notify_all()
         if self._thread is not None:
             self._thread.join(timeout=timeout)
+        if self._gc_hooked:
+            gc_spans(False)
+            self._gc_hooked = False
 
     def __enter__(self):
         return self.start()
@@ -286,6 +298,8 @@ class DiscoveryServer:
                     self._run_batch(work)
             else:
                 self._run_barrier(work)
+            if self._trace:
+                self._free_s = self._now()
 
     def _epoch_barrier(self):
         """Pin one consistent epoch for a whole engine call: hold the
@@ -318,6 +332,14 @@ class DiscoveryServer:
             with recording(rec), \
                     rec.span("batch", tid="dispatcher",
                              requests=len(jobs)) as bspan:
+                if self._trace:
+                    # the former's window: the dispatcher was free and the
+                    # oldest request waited; the rest of a request's queue
+                    # wait was spent behind earlier work
+                    f0 = max(min(p.enqueue_s for p in batch.requests),
+                             self._free_s)
+                    if start > f0:
+                        rec.record("form", f0, start, requests=len(jobs))
                 with contextlib.ExitStack() as stack:
                     # pin_epoch measures lock + mutation-barrier wait; the
                     # barrier stays held for the whole dispatch below

@@ -2,10 +2,15 @@
 percentile snapshots on a fake clock, span nesting/ordering, the Chrome
 trace-event export schema, the per-request flight recorder (span presence
 and queue+batch coverage of end-to-end latency), metrics flowing from every
-instrumented layer, and parity — tracing/metrics/sync-timing change no ids
-and no scores."""
+instrumented layer, the engine host's spans inside ``execute`` and their
+counts, the spans' profiler annotations on the profiler's clock, and
+parity — tracing and metrics change no ids and no scores."""
+import gc
+import glob
 import json
 import math
+import os
+import time
 
 import numpy as np
 import pytest
@@ -15,6 +20,7 @@ from repro import obs
 from repro.core.lake import synthetic_lake
 from repro.obs.metrics import (Histogram, MetricsRegistry, NULL_REGISTRY,
                                NullRegistry)
+from repro.obs import trace as otrace
 from repro.obs.trace import (NULL_RECORDER, Recorder, Span, chrome_trace,
                              current, recording)
 from repro.serve.engine import DiscoveryEngine
@@ -127,14 +133,20 @@ def test_null_registry_is_shared_noop():
 def test_enable_disable_and_sync_timing():
     assert not obs.enabled()
     assert obs.registry() is NULL_REGISTRY
-    reg = obs.enable(sync_timing=True)
-    assert obs.enabled() and obs.registry() is reg and obs.sync_timing()
+    reg = obs.enable()
+    assert obs.enabled() and obs.registry() is reg
     reg.counter("x").inc()
     # enable() makes a FRESH registry: no cross-test pollution
     reg2 = obs.enable()
     assert reg2 is not reg and reg2.counter("x").value == 0.0
     obs.disable()
-    assert obs.registry() is NULL_REGISTRY and not obs.sync_timing()
+    assert obs.registry() is NULL_REGISTRY
+    # device time comes from the profiler trace: the synchronized-timing
+    # mode that serialised dispatch is gone
+    assert not hasattr(obs, "set_sync_timing")
+    assert not hasattr(obs, "sync_timing")
+    with pytest.raises(TypeError):
+        obs.enable(sync_timing=True)
 
 
 # ------------------------------------------------------------------ tracing
@@ -256,7 +268,16 @@ def test_flight_recorder_and_metrics_end_to_end(tmp_path):
         assert snap["counters"]["exec.plans"] >= 1
         assert snap["counters"]["cache.result.miss"] >= 1
         assert "server.batch_seconds" in snap["histograms"]
-        assert "shard.probe_seconds.0" in snap["histograms"]
+        # per-shard dispatch is a span under its probe, not a host-clock
+        # histogram
+        for r in resps:
+            shard = r.trace.find("shard:0")
+            assert shard is not None and shard.attrs["m_cap"] >= 1
+            assert shard.t0 >= r.trace.find("execute").t0
+        assert not any(n.startswith(("shard.probe_seconds", "exec.probe",
+                                     "exec.dag", "exec.compile_seconds"))
+                       for n in snap["histograms"])
+        assert "shard.imbalance" not in snap["gauges"]
         # stats() is a thin reader of the same registry
         st = srv.stats()
         assert st["served"] == int(reg.counter("server.served").value)
@@ -300,12 +321,12 @@ def test_retrace_counter_bridges_trace_counts():
 
 
 def test_observability_changes_no_ids_or_scores():
-    """Parity: tracing + metrics + synchronized timing are observation only."""
+    """Parity: tracing + metrics are observation only."""
     lake = obs_lake()
     queries = obs_queries(lake)
     with DiscoveryServer(DiscoveryEngine(lake, live=True)) as srv:
         base = [f.result() for f in [srv.submit(q) for q in queries]]
-    obs.enable(sync_timing=True)
+    obs.enable()
     with DiscoveryServer(DiscoveryEngine(lake, live=True),
                          trace=True) as srv:
         traced = [f.result() for f in [srv.submit(q) for q in queries]]
@@ -336,3 +357,234 @@ def test_loadgen_report_queue_percentiles():
     d = rep.as_dict()
     assert d["queue_ms_p50"] > 0
     assert d["queue_ms_p99"] >= d["queue_ms_p50"]
+
+
+# ------------------------------------------------ engine host spans
+
+HOST_SPANS = ["plan", "optimize", "lower", "hash", "capacity"]
+
+
+def _one_batch(srv, queries, clock=None):
+    """Submit ``queries`` to a parked server, let them wait past the
+    interactive window (on ``clock`` when the server runs on one), then
+    serve them as one batch; returns the responses."""
+    futs = [srv.submit(q) for q in queries]
+    if clock is not None:
+        clock.advance(0.5)
+    srv.start()
+    out = [f.result(timeout=60) for f in futs]
+    srv.stop()
+    assert {r.batch_size for r in out} == {len(queries)}
+    return out
+
+
+def _batch(resp):
+    return resp.trace.children[1]
+
+
+def test_engine_host_spans_under_execute_in_order():
+    lake = obs_lake()
+    clock = FakeClock(1.0)
+    srv = DiscoveryServer(DiscoveryEngine(lake, live=True), trace=True,
+                          start=False, now=clock)
+    resps = _one_batch(srv, obs_queries(lake), clock)
+    batch = _batch(resps[0])
+    execute = batch.find("execute")
+    names = [c.name for c in execute.children if c.name != "gc"]
+    first_probe = next(i for i, n in enumerate(names)
+                       if n.startswith("probe:"))
+    assert names[:first_probe] == HOST_SPANS
+    assert execute.find("plan").attrs == {"requests": 3, "plan_hits": 0}
+    assert execute.find("lower").attrs == {"plans": 3}
+    # the requests waited for the window: the batch's first child is the
+    # former's window, from the oldest enqueue to the batch's start
+    form = batch.children[0]
+    assert form.name == "form" and form.attrs == {"requests": 3}
+    assert (form.t0, form.t1) == (1.0, 1.5)
+    assert form.t1 <= batch.t0
+
+
+def test_form_span_starts_when_the_dispatcher_was_free():
+    lake = obs_lake()
+    clock = FakeClock(1.0)
+    srv = DiscoveryServer(DiscoveryEngine(lake, live=True), trace=True,
+                          start=False, now=clock)
+    queries = obs_queries(lake)
+    srv._free_s = 1.2            # the dispatcher was busy until then
+    resp = _one_batch(srv, queries[:1], clock)[0]
+    form = _batch(resp).children[0]
+    assert (form.t0, form.t1) == (1.2, 1.5)
+    # a request that found the dispatcher busy until its batch started
+    # records no form span: all of its wait was behind earlier work
+    clock.advance(1.0)           # enqueued at 2.5, served at 3.0
+    srv._free_s = 3.0
+    resp = _one_batch(srv, queries[:1], clock)[0]
+    assert _batch(resp).find("form") is None
+
+
+def test_hash_misses_count_values_new_to_the_memo():
+    lake = obs_lake()
+    t = lake.tables[3]
+    sc_vals = list(t.columns[0][:8])
+    kw_vals = [t.columns[1][0], t.columns[1][2], t.columns[2][1]]
+    mc_vals = [(t.columns[0][r], t.columns[2][r]) for r in range(5)]
+    # no intersection, so the optimizer hashes nothing before ``hash``
+    queries = [(blend.sc(sc_vals, k=20) | blend.kw(kw_vals, k=20)).top(10),
+               (blend.mc(mc_vals, k=20) - blend.kw(kw_vals[:1],
+                                                   k=20)).top(10)]
+    engine = DiscoveryEngine(lake, live=True)
+    distinct = set(sc_vals) | set(kw_vals) | {v for tup in mc_vals
+                                              for v in tup}
+    hashed = len(sc_vals) + len(kw_vals) + 1 + 2 * len(set(mc_vals))
+    first, second = (
+        _batch(_one_batch(DiscoveryServer(engine, trace=True, start=False),
+                          queries)[0]) for _ in range(2))
+    assert first.find("hash").attrs == {"values": hashed,
+                                        "misses": len(distinct),
+                                        "superkeys": len(set(mc_vals))}
+    assert second.find("hash").attrs == {"values": hashed, "misses": 0,
+                                         "superkeys": len(set(mc_vals))}
+    assert first.find("capacity").attrs == {"hashes": hashed}
+    # the second batch's compiles come from the plan memo
+    assert first.find("plan").attrs["plan_hits"] == 0
+    assert second.find("plan").attrs["plan_hits"] == len(queries)
+
+
+def test_optimize_stats_scans_count_host_counts_calls(monkeypatch):
+    lake = obs_lake()
+    t = lake.tables[3]
+    engine = DiscoveryEngine(lake, live=True)
+    store = engine.session.executor.index
+    calls = []
+    orig = type(store).host_counts
+
+    def counting(self, q, live_only=False, **kw):
+        calls.append(live_only)
+        return orig(self, q, live_only=live_only, **kw)
+
+    monkeypatch.setattr(type(store), "host_counts", counting)
+    sc = blend.sc(list(t.columns[0][:8]), k=20)
+    kw = blend.kw([t.columns[1][0], t.columns[1][2]], k=20)
+    mc = blend.mc([(t.columns[0][r], t.columns[1][r]) for r in range(4)],
+                  k=20)
+    queries = [(sc & kw & mc).top(10), (sc & kw).top(10), (sc | kw).top(10)]
+    srv = DiscoveryServer(engine, trace=True, start=False)
+    batch = _batch(_one_batch(srv, queries)[0])
+    opt = batch.find("optimize")
+    stats_calls = [c for c in calls if c]
+    assert opt.attrs["stats_scans"] == len(stats_calls) > 0
+    # one scan per SC/KW seeker ranked, one per column of an MC seeker
+    assert opt.attrs["seekers"] == 5
+    assert opt.attrs["stats_postings"] == \
+        len(stats_calls) * store.n_postings
+    # the optimizer hashed every value first: its lookups hold the misses
+    assert 0 < opt.attrs["hash_misses"] <= opt.attrs["hash_values"]
+    assert batch.find("hash").attrs["misses"] == 0
+    # the one capacity lookup is not a statistics scan
+    assert calls.count(False) == 1
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under a CPU ``jax.profiler`` session; returns (host
+    events by name, the marker's offset onto ``time.monotonic``)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("test_clock_marker"):
+            mark = time.monotonic()
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    events: dict = {}
+    for pl in ProfileData.from_file(path).planes:
+        if not pl.name.startswith("/host"):
+            continue
+        for ln in pl.lines:
+            for ev in ln.events:
+                events.setdefault(ev.name, []).append(
+                    (ev.start_ns * 1e-9, ev.duration_ns * 1e-9))
+    (m0, _), = events["test_clock_marker"]
+    return events, m0 - mark
+
+
+@pytest.mark.parametrize("trace", [True, False])
+def test_spans_annotate_the_profiler_trace(tmp_path, trace):
+    lake = obs_lake()
+    queries = obs_queries(lake)
+    engine = DiscoveryEngine(lake, live=True)
+    with DiscoveryServer(engine) as srv:          # compile outside
+        [f.result() for f in [srv.submit(q) for q in queries]]
+    srv = DiscoveryServer(engine, trace=trace, start=False)
+    got = []
+    events, offset = _profile(
+        tmp_path, lambda: got.extend(_one_batch(srv, queries)))
+    if not trace:
+        assert not {"batch", "execute", "hash", "plan"} & set(events)
+        return
+    batch = _batch(got[0])
+    for name in ("execute", "hash"):
+        span = batch.find(name)
+        (start, dur), = events[name]
+        assert start - offset == pytest.approx(span.t0, abs=1e-3)
+        assert dur == pytest.approx(span.duration, abs=1e-3)
+
+
+def test_untraced_server_makes_no_span_and_no_annotation(monkeypatch):
+    import jax.profiler
+
+    import repro.serve.server as server_mod
+
+    made = {"span": 0, "annotation": 0}
+
+    class CountingSpan(Span):
+        def __init__(self, *a, **kw):
+            made["span"] += 1
+            super().__init__(*a, **kw)
+
+    real = jax.profiler.TraceAnnotation
+
+    def annotation(*a, **kw):
+        made["annotation"] += 1
+        return real(*a, **kw)
+
+    monkeypatch.setattr(otrace, "Span", CountingSpan)
+    monkeypatch.setattr(server_mod, "Span", CountingSpan)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    lake = obs_lake()
+    queries = obs_queries(lake)
+    engine = DiscoveryEngine(lake, live=True)
+    _one_batch(DiscoveryServer(engine, start=False), queries)
+    assert made == {"span": 0, "annotation": 0}
+    _one_batch(DiscoveryServer(engine, trace=True, start=False), queries)
+    assert made["span"] > 0 and made["annotation"] > 0
+
+
+def test_gc_span_under_the_open_span_while_traced(monkeypatch):
+    lake = obs_lake()
+    queries = obs_queries(lake)
+    engine = DiscoveryEngine(lake, live=True)
+    orig = DiscoveryEngine.serve_many
+
+    def collecting(self, qs, **kw):
+        gc.collect()
+        return orig(self, qs, **kw)
+
+    monkeypatch.setattr(DiscoveryEngine, "serve_many", collecting)
+    users = otrace._gc_users
+    srv = DiscoveryServer(engine, trace=True, start=False)
+    batch = _batch(_one_batch(srv, queries)[0])
+    # the explicit full collection; an automatic young one may come first
+    gcs = [c for c in batch.children if c.name == "gc"]
+    assert any(c.attrs["generation"] == 2 for c in gcs)
+    assert all(c.t0 <= c.t1 for c in gcs)
+    # the hook goes with the server; an untraced one never installs it
+    assert otrace._gc_users == users
+    assert (otrace._gc_callback in gc.callbacks) == (users > 0)
+    _one_batch(DiscoveryServer(engine, start=False), queries)
+    assert otrace._gc_users == users
