@@ -545,7 +545,8 @@ def run_fused(ex, plans, optimize=True, cost_model=None, cache=None):
     rec = otrace.current()
     mreg = obs.registry()
     with rec.span("optimize") as sp:
-        stats0 = (ex.n_stat_scans, ex.n_hashed, ex.n_hash_misses)
+        stats0 = (ex.n_stat_scans, ex.n_hashed, ex.n_hash_misses,
+                  getattr(ex.index, "n_stat_postings", 0))
         eps = [optimize_plan(p, ex.seeker_stats, cost_model) if optimize
                else None for p in plans]
         if rec.enabled:
@@ -553,10 +554,10 @@ def run_fused(ex, plans, optimize=True, cost_model=None, cache=None):
             sp.set("seekers", sum(len(g.seekers) for e in eps if e is not None
                                   for g in e.groups.values()))
             sp.set("stats_scans", scans)
-            # on a live store each scan passes over the alive flag of every
-            # posting (``host_counts(live_only=True)``)
-            sp.set("stats_postings", scans * ex.index.n_postings
-                   if hasattr(ex.index, "segments") else 0)
+            # postings whose alive flag the scans gathered: 0 unless a
+            # segment holds a set of dead tables not seen before
+            sp.set("stats_postings",
+                   getattr(ex.index, "n_stat_postings", 0) - stats0[3])
             # seeker_stats hashes through the same memo as the ``hash``
             # span, and before it: its lookups and misses are counted here
             sp.set("hash_values", ex.n_hashed - stats0[1])

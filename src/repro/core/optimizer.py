@@ -18,7 +18,10 @@ Statistics are segment-aware on live lakes: ``stats_fn`` (the executor's
 excluded (``live_only=True``), so the ranking reflects the live lake even
 while dropped tables still occupy probe-window slots awaiting compaction.
 Match *capacities*, by contrast, are sized from the tombstone-inclusive
-counts — a masked posting fills a window slot all the same.
+counts — a masked posting fills a window slot all the same.  A live-only
+lookup costs O(query + tables per segment): a segment with no dead table
+adds no correction, and one with dead tables passes over its postings once
+per set of dead tables (``SegmentStore.host_counts``).
 
 Theorem 1 (output preservation) is tested property-style in
 tests/test_optimizer.py.

@@ -144,6 +144,10 @@ class ShardedStore:
         return sum(s.n_postings for s in self.shards)
 
     @property
+    def n_stat_postings(self) -> int:
+        return sum(s.n_stat_postings for s in self.shards)
+
+    @property
     def epoch(self) -> tuple:
         """Global epoch vector: one counter per shard.  Hashable, compares
         by value — the QueryCache fingerprint and ``Executor.refresh`` use

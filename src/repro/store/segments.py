@@ -77,6 +77,11 @@ class Segment:
     #: a sharded lake pins each shard's segments to its own mesh device
     _dev: dict = field(default_factory=dict, repr=False, compare=False)
     _dev_buckets: dict = field(default_factory=dict, repr=False, compare=False)
+    #: planner statistics: ``tables`` as an int32 array, and the prefix sum
+    #: of dead postings with the dead-table ids it counts (``host_counts``)
+    _table_ids: np.ndarray | None = field(default=None, repr=False,
+                                          compare=False)
+    _dead_csum: tuple | None = field(default=None, repr=False, compare=False)
 
     @property
     def n_padded(self) -> int:
@@ -85,6 +90,15 @@ class Segment:
     @property
     def n_num_padded(self) -> int:
         return len(self.num_rowkey)
+
+    @property
+    def table_ids(self) -> np.ndarray:
+        """``tables`` as an int32 array, built once: a segment is
+        immutable, and converting the tuple per lookup costs more than the
+        lookup."""
+        if self._table_ids is None:
+            self._table_ids = np.asarray(self.tables, np.int32)
+        return self._table_ids
 
     def storage_bytes(self) -> int:
         core = sum(getattr(self, k).nbytes for k in POSTING_KEYS)
@@ -266,6 +280,11 @@ class SegmentStore:
     #: slot-capacity headroom: adding this many tables never grows the
     #: score-vector shape (and therefore never retraces the seekers)
     MIN_HEADROOM = 8
+    #: running total of postings whose alive flag a ``live_only``
+    #: ``host_counts`` gathered (the flight recorder's
+    #: ``optimize.stats_postings`` reads its difference); a class default,
+    #: so a store a snapshot restores counts from 0 as well
+    n_stat_postings = 0
 
     def __init__(self, lake=None, *, bucket_bits: int = 12, seed: int = 0,
                  with_quadrants: bool = True, entries=None,
@@ -370,7 +389,12 @@ class SegmentStore:
         statistics).  ``live_only=False`` (the default) includes tombstoned
         postings — they still occupy probe-window slots, so match capacities
         must cover them; ``live_only=True`` subtracts them for cost
-        estimates (core/optimizer.py seeker ranking)."""
+        estimates (core/optimizer.py seeker ranking).
+
+        Cost per segment: two binary searches of the query, plus for
+        ``live_only`` the alive flags of the segment's tables.  A segment
+        with a dead table subtracts its dead postings through a prefix sum
+        built once per set of dead tables (:meth:`_dead_postings_csum`)."""
         q = np.asarray(q_hashes)
         total = np.zeros(len(q), np.int64)
         for seg in self.segments:
@@ -379,11 +403,32 @@ class SegmentStore:
             hi = np.searchsorted(keys, q, side="right")
             total += hi - lo
             if live_only:
-                dead = ~self.alive[seg.table_id[: seg.n_real]]
-                if dead.any():
-                    csum = np.concatenate([[0], np.cumsum(dead)])
+                csum = self._dead_postings_csum(seg)
+                if csum is not None:
                     total -= csum[hi] - csum[lo]
         return total
+
+    def _dead_postings_csum(self, seg: Segment) -> np.ndarray | None:
+        """Prefix sum (int32, ``n_real + 1``) of ``seg``'s postings whose
+        table is dead, or None when all its tables are live.  Memoized on
+        the segment, keyed on the dead ids themselves rather than the
+        epoch: compaction, snapshot restore and WAL replay set ``alive`` and
+        the epoch outside ``drop_table``, and a key read from ``alive``
+        cannot go stale.  Each rebuild gathers the alive flag of every
+        posting, counted in ``n_stat_postings``."""
+        tids = seg.table_ids
+        dead = tids[~self.alive[tids]]
+        if not len(dead):
+            return None
+        key = dead.tobytes()
+        memo = seg._dead_csum
+        if memo is None or memo[0] != key:
+            csum = np.zeros(seg.n_real + 1, np.int32)
+            np.cumsum(~self.alive[seg.table_id[: seg.n_real]],
+                      dtype=np.int32, out=csum[1:])
+            self.n_stat_postings += seg.n_real
+            memo = seg._dead_csum = (key, csum)
+        return memo[1]
 
     def shape(self) -> dict:
         """Observable index shape (Session.explain): segment/posting layout,

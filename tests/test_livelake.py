@@ -467,6 +467,128 @@ def test_host_counts_live_only_excludes_tombstones():
     assert live.sum() < full.sum()
 
 
+def per_posting_counts(store, q, live_only):
+    """The per-posting formula ``host_counts`` must equal: every posting's
+    alive flag gathered and prefix-summed, in every segment of every
+    shard."""
+    total = np.zeros(len(q), np.int64)
+    for shard in getattr(store, "shards", [store]):
+        for seg in shard.segments:
+            keys = seg.cell_hash[: seg.n_real]
+            lo = np.searchsorted(keys, q, side="left")
+            hi = np.searchsorted(keys, q, side="right")
+            total += hi - lo
+            if live_only:
+                dead = ~shard.alive[seg.table_id[: seg.n_real]]
+                csum = np.concatenate([[0], np.cumsum(dead)])
+                total -= csum[hi] - csum[lo]
+    return total
+
+
+def _then(mutate):
+    """A step that mutates the LiveLake and goes on with it."""
+    def step(ll, tmp_path):
+        mutate(ll)
+        return ll
+    return step
+
+
+def _grow_capacity(ll):
+    for s in getattr(ll.store, "shards", [ll.store]):
+        s.grow_capacity(ll.store.n_tables * 2)
+
+
+def _snapshot_restore(ll, tmp_path):
+    snap.save(ll.store, tmp_path / "lake")
+    return LiveLake(store=snap.load(tmp_path / "lake"), auto_compact=False)
+
+
+# drops 2 and 4 land in one multi-table segment on both stores (a sharded
+# lake places g on shard g % 2); reclaiming ids on compaction reassigns
+# ``alive`` (a sharded lake cannot reclaim)
+COUNT_STEPS = [
+    ("add_table", _then(lambda ll: ll.add_table(extra_table(0)))),
+    ("drop_in_multi_table_segment", _then(lambda ll: ll.drop_table(2))),
+    ("second_drop_same_segment", _then(lambda ll: ll.drop_table(4))),
+    ("drop_last_live_table_of_run",
+     _then(lambda ll: ll.drop_table(max(ll.live_ids())))),
+    ("compact", _then(lambda ll: ll.compact(
+        full=True, reclaim_ids=not hasattr(ll.store, "shards")))),
+    ("drop_after_compact", _then(lambda ll: ll.drop_table(6))),
+    ("snapshot_restore", _snapshot_restore),
+    ("drop_after_restore", _then(lambda ll: ll.drop_table(8))),
+    ("grow_capacity", _then(_grow_capacity)),
+]
+
+
+@pytest.mark.parametrize("last", [name for name, _ in COUNT_STEPS])
+@pytest.mark.parametrize("sharded", [False, True], ids=["single", "sharded"])
+def test_host_counts_live_only_matches_per_posting_formula(sharded, last,
+                                                           tmp_path):
+    """Live-only counts key their tombstone correction on each segment's
+    dead tables; after every mutation of the sequence (up to ``last``)
+    they equal the per-posting formula exactly, and the tombstone-inclusive
+    counts are untouched."""
+    from repro.core.hashing import hash_array
+    from repro.dist.shard import ShardedStore
+    lake = small_live_lake(seed=14)
+    store = ShardedStore(lake, n_shards=2) if sharded else None
+    ll = LiveLake(lake, store=store, auto_compact=False)
+    vals = [v for t in list(lake.tables) + [extra_table(0)]
+            for c in t.columns for v in c]
+    q = np.unique(hash_array(vals))
+    for name, step in COUNT_STEPS:
+        ll = step(ll, tmp_path)
+        for live_only in (True, False, True):        # the memo's second use
+            got = ll.store.host_counts(q, live_only=live_only)
+            np.testing.assert_array_equal(
+                got, per_posting_counts(ll.store, q, live_only), err_msg=name)
+        if name == last:
+            break
+
+
+def test_seeker_ranking_and_answers_match_per_posting_counts(monkeypatch):
+    """With one table tombstoned, the optimizer's cost features, its ranked
+    order, and the served ids and float32 scores of an ``mc & sc & kw``
+    query are those the per-posting live-only formula gives."""
+    from repro.core.cost_model import CostModel
+    from repro.serve.engine import DiscoveryEngine
+    from repro.store.segments import SegmentStore
+    lake = small_live_lake(seed=23)
+    dead, other = lake.tables[2], lake.tables[7]
+    # a second SC seeker: the estimates, not the rules, order the two SCs
+    q = (blend.mc([(dead.columns[0][r], dead.columns[1][r])
+                   for r in range(6)], k=20)
+         & blend.sc(list(dead.columns[0][:10]), k=20)
+         & blend.kw([dead.columns[1][0], other.columns[1][1]], k=20)
+         & blend.sc(list(other.columns[0][:4]), k=20)).top(10)
+    cost = CostModel()
+    for kind in ("KW", "SC", "MC"):
+        cost.weights[kind] = np.array([0.0, 0.0, 0.0, 1.0])   # log1p(freq)
+
+    def served():
+        engine = DiscoveryEngine(lake, live=True, cost_model=cost)
+        engine.drop_table(2)
+        session = engine.session
+        plan = session.compile(q).plan
+        stats = [session.executor.seeker_stats(n.spec)
+                 for n in plan.nodes.values() if n.is_seeker]
+        ranked = session.explain(q, execute=False).physical_order
+        resp = engine.serve(q, fused=True)
+        return stats, ranked, resp.table_ids, np.asarray(resp.scores)
+
+    stats, ranked, ids, scores = served()
+    monkeypatch.setattr(
+        SegmentStore, "host_counts", lambda self, h, live_only=False:
+        per_posting_counts(self, np.asarray(h), live_only))
+    want_stats, want_ranked, want_ids, want_scores = served()
+    assert stats == want_stats
+    assert ranked == want_ranked and any(len(v) == 4 for v in ranked.values())
+    assert ids == want_ids
+    assert scores.dtype == np.float32
+    np.testing.assert_array_equal(scores, want_scores)
+
+
 # --------------------------------------------------------------------------
 # sketch tier: mutation / compaction / snapshot parity (approx discovery)
 # --------------------------------------------------------------------------

@@ -475,13 +475,23 @@ def test_optimize_stats_scans_count_host_counts_calls(monkeypatch):
     assert opt.attrs["stats_scans"] == len(stats_calls) > 0
     # one scan per SC/KW seeker ranked, one per column of an MC seeker
     assert opt.attrs["seekers"] == 5
-    assert opt.attrs["stats_postings"] == \
-        len(stats_calls) * store.n_postings
+    # a tombstone-free lake: no scan gathers a posting's alive flag
+    assert opt.attrs["stats_postings"] == 0
     # the optimizer hashed every value first: its lookups hold the misses
     assert 0 < opt.attrs["hash_misses"] <= opt.attrs["hash_values"]
     assert batch.find("hash").attrs["misses"] == 0
     # the one capacity lookup is not a statistics scan
     assert calls.count(False) == 1
+    # a drop in a multi-table segment: the first batch passes over that
+    # segment's postings once, and a repeat finds the memo
+    owner = next(s for s in store.segments if 5 in s.tables)
+    assert len(owner.tables) > 1
+    engine.drop_table(5)
+    first, again = (_batch(_one_batch(srv, queries)[0]).find("optimize")
+                    for _ in range(2))
+    assert first.attrs["stats_scans"] == again.attrs["stats_scans"] > 0
+    assert first.attrs["stats_postings"] == owner.n_real
+    assert again.attrs["stats_postings"] == 0
 
 
 def _profile(tmp_path, fn):
