@@ -68,10 +68,9 @@ def _probe_query(lake, tab=11, nq=48):
 
 
 def _hot_values(lake, n_vals=96, lo=520, hi=1000, shard_lim=120):
-    """Probe values hot enough that the 1-shard capacity window sits on the
-    top rung (counts > 512 -> m_cap 1024) while every 8-shard window stays a
-    full rung below (per-shard counts <= 120 -> m_cap 128) — the per-device
-    work reduction the sharded capacity ladder buys on skewed lakes."""
+    """Probe values hot enough that the 1-shard window holds more than 512
+    postings of each while no 8-shard window holds more than 120 — the
+    per-device work reduction sharding buys on skewed lakes."""
     from repro.core.hashing import hash_array
     store = ShardedStore(lake, n_shards=8)
     pool, seen = [], set()

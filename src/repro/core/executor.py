@@ -34,10 +34,12 @@ from repro.core.optimizer import optimize as optimize_plan
 from repro.core.plan import Plan, SeekerSpec
 from repro.core import sketch as sk
 
-# the match-capacity ladder: every seeker launch uses one of these static
-# capacities, so the jit cache holds at most len(CAP_LADDER) variants per
-# (seeker, query-pad) shape instead of one per observed match count — and a
-# coarse ladder keeps the bucket stable across draws from the same workload
+# the unfused seekers' match-capacity ladder: every such launch uses one of
+# these static capacities, so the jit cache holds at most len(CAP_LADDER)
+# variants per (seeker, query-pad) shape instead of one per observed match
+# count — and a coarse ladder keeps the bucket stable across draws from the
+# same workload.  ``m_cap_max`` tops it.  The fused path (core/fused.py)
+# sizes one flat window from the summed counts instead and uses neither.
 CAP_LADDER = (32, 128, 512, 1024)
 PAD_SENTINEL = MISSING                    # reserved: never a real cell hash
 

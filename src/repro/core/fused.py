@@ -9,11 +9,12 @@ The fused path collapses a plan (or a whole ``serve_many`` batch) to
 
 1. **Batched seeker dispatch** — all same-kind seekers, across every plan in
    the batch, are concatenated into one padded query array with per-row
-   seeker ids and per-row (ladder-quantized) capacities, probed once through
-   ``MatchEngine.probe_capped`` and grouped-by into a stacked
+   seeker ids, probed once into one flat window that holds every posting of
+   every query value (``MatchEngine.window``) and grouped-by into a stacked
    ``[n_seekers, n_tables]`` score matrix (seekers.py ``*_seeker_seg``).
-   Capacity lookups batch into ONE ``host_counts`` call over every seeker's
-   hashes.
+   The program walks the window in chunks of slots; how many comes from
+   ONE ``host_counts`` call over every seeker's hashes (the group's summed
+   match counts), so no value is cut however many postings it has.
 2. **Whole-DAG device compilation** — the post-seeker combiner DAG
    (top-k / intersect / union / difference / counter / optimizer mask
    threading) is elementwise over ``[n_tables]`` vectors, so the entire DAG
@@ -22,10 +23,10 @@ The fused path collapses a plan (or a whole ``serve_many`` batch) to
 
 Bit-identity with the unfused executor rests on two invariants:
 
-* per-seeker probe windows under ``probe_capped`` hold exactly the postings
-  a dedicated launch at that seeker's capacity would hold, and every seeker
-  score is a sum / max of 0-or-1 float contributions (or a QCR ratio of such
-  sums), so the stacked rows equal the dedicated launches bit-for-bit;
+* the flat window holds every posting of each seeker's query values, as an
+  exact dedicated launch would, and every seeker score is a sum / max of
+  0-or-1 float contributions (or a QCR ratio of such sums), so the stacked
+  rows equal the dedicated launches bit-for-bit;
 * a seeker run under the optimizer's threaded ``allowed`` mask equals
   ``where(allowed, unrestricted_scores, 0)`` followed by the same top-k —
   the mask is constant per table and is ANDed into contributions *before* a
@@ -39,9 +40,10 @@ served from or stored into the cache, so partial hits stay bit-identical to
 a cold run.
 
 Retrace-freedom: the batch query width, the tuple-block width, the seeker
-count and the shared capacity window are all quantized onto power-of-two /
-capacity ladders, and the DAG program is keyed on plan topology — re-running
-any plan shape with new values of the same buckets is zero-trace
+count and the window's chunk are all quantized onto power-of-two ladders
+(the number of chunks is an operand), and the DAG program is keyed on plan
+topology — re-running any plan shape with new values of the same buckets
+is zero-trace
 (``seekers.TRACE_COUNTS``-asserted in tests/test_fused.py).
 """
 from __future__ import annotations
@@ -60,7 +62,6 @@ from repro.core.combiners import ResultSet
 from repro.obs import trace as otrace
 from repro.core.executor import (ExecInfo, OverflowSlice, PAD_SENTINEL,
                                  _pow2_at_least)
-from repro.core.hashing import row_superkey, split_u64
 from repro.core.optimizer import optimize as optimize_plan
 
 
@@ -76,14 +77,11 @@ class _Task:
     qbit: np.ndarray | None = None   # C: k0/k1 split bits
     th: np.ndarray | None = None     # MC: [nt, n_cols] hashed tuples
     init_col: np.ndarray | None = None
-    qk_lo: np.ndarray | None = None
-    qk_hi: np.ndarray | None = None
     nt: int = 0                      # MC: deduped tuple count
-    m_cap: int = 0                   # this seeker's capacity-ladder rung
-    #: sharded lakes: per-shard capacity rungs from per-shard counts — a
-    #: shard probes only its own postings, so its window can be (much)
-    #: smaller than the global rung; exact as long as no shard overflows
-    shard_caps: tuple = ()
+    #: postings of each probed value (MC: each tuple's initiator value);
+    #: ``[n_shards, n]`` on a sharded lake, where a shard probes only its
+    #: own
+    counts: np.ndarray | None = None
     group_key: tuple = ()
     row: int = -1                    # row in the group's stacked output
     head: object = None              # canonical task for this spec: dupes
@@ -207,20 +205,43 @@ def _compile_plan(plan, optimize, ep, cache, plan_idx) -> _PlanProg:
 
 
 # --------------------------------------------------------------------------
-# batched hashing + ONE host_counts call for every capacity pick
+# batched hashing + ONE host_counts call for every window size
 # --------------------------------------------------------------------------
 
-def _hash_tasks(ex, tasks):
+#: a seeker program walks its flat window in chunks of a power of two of
+#: slots between these two: a small batch takes one chunk the size of its
+#: postings, a large one as many of the largest as its postings need.  The
+#: chunk, not the count, is the program's static shape, so a serving mix
+#: compiles a few programs, not one per window size (a v5e compiles one in
+#: 1-15 s)
+MIN_CHUNK, MAX_CHUNK = 1 << 10, 1 << 14
+#: the smallest padded query array (values, or MC tuples) and seeker count
+#: of a launch, each size being a program of its own.  The device searches
+#: every padded value's run (~22 gathers each), so the width floor stays
+#: low; 16 seekers hold one of each kind for a whole batch of the server's
+#: default policy
+MIN_WIDTH, MIN_SEEKERS = 256, 16
+
+
+def _walk(matched: int) -> tuple:
+    """(chunk, n_chunks) of a flat window that holds ``matched`` postings."""
+    chunk = min(_pow2(matched, lo=MIN_CHUNK), MAX_CHUNK)
+    return chunk, -(-matched // chunk)
+
+
+def _hash_tasks(ex, groups) -> dict:
     """Hash every pending seeker's query values (through the executor's
-    memoized value-hash cache) and pick every capacity from one batched
-    ``host_counts`` lookup over the concatenated hash arrays."""
+    memoized value-hash cache) and size every group's flat window from one
+    batched ``host_counts`` lookup over the concatenated hash arrays.
+    Returns each group's walk of its window, ``(chunk, n_chunks)``, keyed
+    like ``groups`` (a tuple of per-shard walks on a sharded lake)."""
+    tasks = [t for g in groups.values() for t in g]
     if not tasks:
-        return
+        return {}
     rec = otrace.current()
     reqs = []
     with rec.span("hash") as sp:
         hashed0, misses0 = ex.n_hashed, ex.n_hash_misses
-        superkeys = 0
         for t in tasks:
             spec = t.spec
             if spec.kind in ("SC", "KW"):
@@ -240,48 +261,68 @@ def _hash_tasks(ex, tasks):
                 t.th = np.stack([ex._hash_many([v[c] for v in values])
                                  for c in range(n_cols)], axis=1) \
                     if values else np.zeros((0, n_cols), np.uint32)
-                qks = np.array([row_superkey(t.th[i],
-                                             np.zeros(n_cols, np.int64))
-                                for i in range(t.nt)], np.uint64)
-                t.qk_lo, t.qk_hi = split_u64(qks)
                 reqs.append(t.th.reshape(-1))
-                superkeys += t.nt
         if rec.enabled:
             sp.set("values", ex.n_hashed - hashed0)
             sp.set("misses", ex.n_hash_misses - misses0)
-            sp.set("superkeys", superkeys)
     with rec.span("capacity") as sp:
         lens = np.array([len(r) for r in reqs], np.int64)
         offs = np.concatenate([[0], np.cumsum(lens)])
         all_h = np.concatenate(reqs) if offs[-1] else np.zeros(0, np.uint32)
-        if rec.enabled:
-            sp.set("hashes", len(all_h))
         n_shards = getattr(ex, "n_shards", 0)
         if n_shards:
-            # per-shard counts in the same ONE batched lookup: global
-            # capacities (and the MC initiator-column pick) come from the
-            # summed counts — identical to a 1-shard run — while each
-            # shard's probe window sizes to its own counts (a shard only
-            # holds its own tables' postings)
+            # per-shard counts in the same ONE batched lookup: the MC
+            # initiator-column pick comes from the summed counts —
+            # identical to a 1-shard run — while each shard's window sizes
+            # to its own counts (a shard only holds its own tables'
+            # postings)
             per = ex.index.host_counts(all_h, per_shard=True)
             counts = per.sum(axis=0)
         else:
             counts = ex.index.host_counts(all_h)
         for i, t in enumerate(tasks):
-            c = counts[offs[i]:offs[i + 1]]
+            cut = slice(offs[i], offs[i + 1])
+            pick = slice(None)
             if t.spec.kind == "MC":
-                cm = c.reshape(t.nt, t.spec.n_cols) if t.nt \
-                    else np.zeros((0, t.spec.n_cols), np.int64)
-                t.init_col = np.argmin(cm, axis=1).astype(np.int32) \
-                    if t.nt else np.zeros(0, np.int32)
-                t.m_cap = ex._quantize_cap(int(cm.max(initial=1)))
-            else:
-                t.m_cap = ex._quantize_cap(int(c.max(initial=1)))
-            if n_shards:
-                t.shard_caps = tuple(
-                    ex._quantize_cap(int(per[s, offs[i]:offs[i + 1]]
-                                         .max(initial=1)))
-                    for s in range(n_shards))
+                cm = counts[cut].reshape(t.nt, t.spec.n_cols)
+                t.init_col = np.argmin(cm, axis=1).astype(np.int32)
+                # the window holds each tuple's initiator postings
+                pick = np.arange(t.nt) * t.spec.n_cols + t.init_col
+            t.counts = (per[:, cut] if n_shards else counts[cut])[..., pick]
+        walks, matched = {}, 0
+        for key, g in groups.items():
+            c = np.concatenate([t.counts for t in g], axis=-1)
+            if key[0] in ("SC", "KW"):
+                # the window holds each distinct value of the group once
+                c = c[..., _distinct(g)[2]]
+            c = c.sum(axis=-1)
+            matched += int(c.sum())
+            walks[key] = tuple(_walk(int(m)) for m in c) if n_shards \
+                else _walk(int(c))
+        if rec.enabled:
+            sp.set("hashes", len(all_h))
+            sp.set("slots", sum(_slots(w) for w in walks.values()))
+            sp.set("matched", matched)
+    return walks
+
+
+def _distinct(tasks) -> tuple:
+    """The distinct values of a group of SC or KW seekers: (their hashes,
+    ``[n, len(tasks)]`` which seekers hold each, and each one's first place
+    in the seekers' values laid end to end)."""
+    h = np.concatenate([t.h for t in tasks])
+    u, first, inv = np.unique(h, return_index=True, return_inverse=True)
+    member = np.zeros((len(u), len(tasks)), bool)
+    member[inv.reshape(-1), np.repeat(np.arange(len(tasks)),
+                                      [len(t.h) for t in tasks])] = True
+    return u, member, first
+
+
+def _slots(walk) -> int:
+    """Slots a walk (or a tuple of per-shard walks) covers."""
+    if isinstance(walk[0], tuple):
+        return sum(_slots(w) for w in walk)
+    return walk[0] * walk[1]
 
 
 # --------------------------------------------------------------------------
@@ -292,14 +333,17 @@ def _pow2(n: int, lo: int) -> int:
     return _pow2_at_least(max(n, 1), lo=lo, hi=1 << 30)
 
 
-def _launch_group(ex, key, tasks, failed=None):
+def _launch_group(ex, key, tasks, walk, failed=None):
     """Dispatch one seeker group as a single device program.  Returns
     (scores [n_seekers_p, n_tables], overflow [n_seekers_p]) — both lazy.
     ``tasks`` are the deduped head tasks of the group (run_fused collapses
     identical specs before hashing).
 
+    ``walk`` is the window's ``(chunk, n_chunks)`` (``_hash_tasks``; a tuple
+    of per-shard walks on a sharded lake).
+
     Sharded executors (``ex.engines``) dispatch the same batched program
-    once per shard — same query operands, per-shard capacity windows — and
+    once per shard — same query operands, per-shard windows — and
     return *tuples* of per-shard (scores, overflow).  Each shard holds
     whole tables, so summing the per-shard matrices (inside ``_run_dag``)
     is exact: every table slot is nonzero on exactly one shard.  The whole
@@ -320,25 +364,14 @@ def _launch_group(ex, key, tasks, failed=None):
     # couple of buckets, so reshuffled batches stop retracing.  The padding
     # itself is dead rows in a [nsp, n_tables] matrix — negligible next to
     # the probe work, and single-plan latency is unaffected (measured).
-    nsp = _pow2(len(tasks), lo=8)
-    spans = []
-
-    def fill_caps(caps, shard):
-        m_cap = 1
-        for (off, n), t in zip(spans, tasks):
-            c = t.m_cap if shard is None else t.shard_caps[shard]
-            caps[off:off + n] = c
-            m_cap = max(m_cap, c)
-        return m_cap
+    nsp = _pow2(len(tasks), lo=MIN_SEEKERS)
 
     if kind == "MC":
         n_cols = key[1]
         total = sum(t.nt for t in tasks)
-        width = _pow2(total, lo=8)
+        width = _pow2(total, lo=MIN_WIDTH)
         th = np.zeros((width, n_cols), np.uint32)
         init = np.zeros(width, np.int32)
-        qlo = np.zeros(width, np.uint32)
-        qhi = np.zeros(width, np.uint32)
         seg = np.zeros(width, np.int32)
         tmask = np.zeros(width, bool)
         off = 0
@@ -346,26 +379,36 @@ def _launch_group(ex, key, tasks, failed=None):
             n = t.nt
             th[off:off + n] = t.th
             init[off:off + n] = t.init_col
-            qlo[off:off + n] = t.qk_lo
-            qhi[off:off + n] = t.qk_hi
             seg[off:off + n] = i
             tmask[off:off + n] = True
-            spans.append((off, n))
             off += n
 
         # numpy operands go straight into the jitted call: jit's own
         # device_put of the whole operand list is much cheaper than
         # per-array jnp.asarray round-trips on the hot path (and, being
         # uncommitted, they follow each shard engine to its device)
-        def dispatch(eng, caps, m_cap):
+        def dispatch(eng, walk):
             return seek.mc_seeker_seg(
-                eng, th, init, qlo, qhi, seg, caps,
-                m_cap=m_cap, n_seekers=nsp, n_tables=ex.n_tables,
-                n_cols=n_cols, row_stride=ex.index.row_stride,
+                eng, th, init, seg, np.int32(walk[1]), chunk=walk[0],
+                n_seekers=nsp, n_tables=ex.n_tables, n_cols=n_cols,
                 tuple_mask=tmask)
+    elif kind in ("SC", "KW"):
+        h, member, _ = _distinct(tasks)
+        width = _pow2(len(h), lo=MIN_WIDTH)
+        qh = np.full(width, PAD_SENTINEL, np.uint32)
+        qh[:len(h)] = h
+        qm = np.arange(width) < len(h)
+        mem = np.zeros((width, nsp), bool)
+        mem[:len(h), :len(tasks)] = member
+        fn, opts = (seek.sc_seeker_seg, dict(max_cols=ex.max_cols)) \
+            if kind == "SC" else (seek.kw_seeker_seg, {})
+
+        def dispatch(eng, walk):
+            return fn(eng, qh, qm, mem, np.int32(walk[1]), chunk=walk[0],
+                      n_tables=ex.n_tables, **opts)
     else:
         total = sum(len(t.h) for t in tasks)
-        width = _pow2(total, lo=16)
+        width = _pow2(total, lo=MIN_WIDTH)
         qh = np.full(width, PAD_SENTINEL, np.uint32)
         qm = np.zeros(width, bool)
         seg = np.zeros(width, np.int32)
@@ -376,23 +419,13 @@ def _launch_group(ex, key, tasks, failed=None):
             qh[off:off + n] = t.h
             qm[off:off + n] = True
             seg[off:off + n] = i
-            if kind == "C":
-                qb[off:off + n] = t.qbit
-            spans.append((off, n))
+            qb[off:off + n] = t.qbit
             off += n
 
-        def dispatch(eng, caps, m_cap):
-            if kind == "SC":
-                return seek.sc_seeker_seg(eng, qh, qm, seg, caps,
-                                          m_cap=m_cap, n_seekers=nsp,
-                                          n_tables=ex.n_tables,
-                                          max_cols=ex.max_cols)
-            if kind == "KW":
-                return seek.kw_seeker_seg(eng, qh, qm, seg, caps,
-                                          m_cap=m_cap, n_seekers=nsp,
-                                          n_tables=ex.n_tables)
-            return seek.c_seeker_seg(eng, qh, qm, qb, seg, caps,
-                                     m_cap=m_cap, row_cap=ex.row_cap,
+        def dispatch(eng, walk):
+            chunk, n_chunks = walk[0], np.int32(walk[1])
+            return seek.c_seeker_seg(eng, qh, qm, qb, seg, n_chunks,
+                                     chunk=chunk, row_cap=ex.row_cap,
                                      n_seekers=nsp, n_tables=ex.n_tables,
                                      max_cols=ex.max_cols, h_sample=key[1],
                                      sampling=key[2],
@@ -402,18 +435,16 @@ def _launch_group(ex, key, tasks, failed=None):
     rec = otrace.current()
     mreg = obs.registry()
     if engines is None:
-        caps = np.zeros(width, np.int32)
-        m_cap = fill_caps(caps, None)
-        with rec.span("shard:0", m_cap=m_cap, seekers=len(tasks)):
-            return dispatch(ex.engine, caps, m_cap)
+        with rec.span("shard:0", slots=_slots(walk), chunk=walk[0],
+                      seekers=len(tasks)):
+            return dispatch(ex.engine, walk)
     scores, ovf = [], []
     for s, eng in enumerate(engines):
-        caps = np.zeros(width, np.int32)
-        m_cap = fill_caps(caps, s)
-        with rec.span(f"shard:{s}", m_cap=m_cap, seekers=len(tasks)):
+        with rec.span(f"shard:{s}", slots=_slots(walk[s]),
+                      chunk=walk[s][0], seekers=len(tasks)):
             try:
                 faults.checkpoint(f"shard.probe.{s}")
-                sc, ov = dispatch(eng, caps, m_cap)
+                sc, ov = dispatch(eng, walk[s])
             except Exception:                        # noqa: BLE001
                 # InjectedCrash (BaseException) deliberately passes through:
                 # a simulated kill -9 must not be absorbed as a shard retry
@@ -421,7 +452,7 @@ def _launch_group(ex, key, tasks, failed=None):
                 try:
                     eng = ex.reset_shard(s)
                     faults.checkpoint(f"shard.probe.{s}")
-                    sc, ov = dispatch(eng, caps, m_cap)
+                    sc, ov = dispatch(eng, walk[s])
                     mreg.counter("shard.retries").inc()
                 except Exception:                    # noqa: BLE001
                     # rebuilt engine failed too: drop the shard from the
@@ -575,12 +606,11 @@ def run_fused(ex, plans, optimize=True, cost_model=None, cache=None):
     heads: dict = {}
     for t in tasks:
         t.head = heads.setdefault(t.spec, t)
-    _hash_tasks(ex, list(heads.values()))
-
     groups: dict[tuple, list] = {}
     for h in heads.values():
         h.group_key = _group_key(h.spec)
         groups.setdefault(h.group_key, []).append(h)
+    walks = _hash_tasks(ex, groups)
     group_out: dict[tuple, tuple] = {}
     launch_seconds: dict[tuple, float] = {}
     failed_shards: set = set()
@@ -590,8 +620,9 @@ def run_fused(ex, plans, optimize=True, cost_model=None, cache=None):
         # exec.compiles and the span's ``compiled`` show which step did
         tr0 = sum(seek.TRACE_COUNTS.values())
         t0 = time.perf_counter()
-        with rec.span("probe:" + kind_name, seekers=len(groups[key])) as sp:
-            group_out[key] = _launch_group(ex, key, groups[key],
+        with rec.span("probe:" + kind_name, seekers=len(groups[key]),
+                      slots=_slots(walks[key])) as sp:
+            group_out[key] = _launch_group(ex, key, groups[key], walks[key],
                                            failed=failed_shards)
         launch_seconds[key] = time.perf_counter() - t0
         if sum(seek.TRACE_COUNTS.values()) > tr0:

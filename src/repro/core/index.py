@@ -126,6 +126,9 @@ class UnifiedIndex:
             "quadrant": jnp.asarray(self.quadrant),
             "rank_conv": jnp.asarray(self.rank_conv),
             "rank_rand": jnp.asarray(self.rank_rand),
+            "row_cells": jnp.asarray(row_cells_view(
+                self.cell_hash, self.table_id, self.col_id, self.row_id,
+                self.max_cols)),
             "num_rowkey": jnp.asarray(self.num_rowkey),
             "num_table": jnp.asarray(self.table_id[self.num_perm]),
             "num_col": jnp.asarray(self.col_id[self.num_perm]),
@@ -266,6 +269,26 @@ def numeric_view(parts: dict, row_stride: int):
     np_order = np.argsort(rowkey, kind="stable")
     return numeric[np_order].astype(np.int32), \
         rowkey[np_order].astype(np.int32)
+
+
+def row_cells_view(cell_hash, table_id, col_id, row_id, width: int):
+    """Each posting's row as its cells' hashes, ``[n, width]`` (u32): column
+    ``c`` holds the hash of the row's cell in column ``c``, and a column the
+    row lacks repeats the posting's own hash.  Whether a posting's row holds
+    a value is then one gather and ``width`` compares, whatever the value's
+    count (the fused MC validation); a repeated cell of the row changes no
+    answer."""
+    out = np.repeat(np.asarray(cell_hash, np.uint32)[:, None], width, axis=1)
+    if not len(cell_hash):
+        return out
+    rowkey = table_id.astype(np.int64) * (int(row_id.max()) + 1) + row_id
+    _, row = np.unique(rowkey, return_inverse=True)
+    row = row.reshape(-1)
+    cells = np.zeros((int(row.max()) + 1, width), np.uint32)
+    held = np.zeros(cells.shape, bool)
+    cells[row, col_id] = cell_hash
+    held[row, col_id] = True
+    return np.where(held[row], cells[row], out)
 
 
 def build_index(lake: DataLake, bucket_bits: int = 12, seed: int = 0,

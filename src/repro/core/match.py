@@ -5,13 +5,20 @@ layouts, and the low-level match primitives.  Since the LiveLake subsystem
 (repro/store) the engine is *segment-aware*: the resident index is an ordered
 list of immutable sorted segments (one large base + small L0 deltas) and
 
-* ``probe(q_hash, q_mask, m_cap)`` fans out over the segments — each segment
-  has its own sorted run, padded-bucket layout and capacity-ladder entry —
-  and concatenates the per-segment posting windows along the match axis, so
-  seekers see one ``[nq, n_segments * m_cap]`` window and stay unchanged.
-* tombstone masks (dropped tables) are applied to ``valid`` inside ``probe``
-  / ``rowjoin``, *before* any group-by stage, so mutation parity with a
-  from-scratch rebuild holds bit-exactly.
+* ``window(q_hash, q_mask)`` — the flat probe window of the fused seekers:
+  every query hash's run of postings in every segment, laid end to end.
+  The seekers walk it in fixed-size chunks of slots (``slots``), as many as
+  the summed match counts need, so a hot value holds as many slots as it
+  has postings and nothing is cut; postings past the last chunk (a stale
+  count) are counted as overflow.
+* ``probe(q_hash, q_mask, m_cap)`` — the unfused seekers' dense window:
+  ``m_cap`` slots per query and segment, concatenated along the match axis
+  (``[nq, n_segments * m_cap]``).
+* tombstone masks (dropped tables) are applied to ``valid`` inside
+  ``probe`` / ``rowjoin``, *before* any group-by stage, and to the fused
+  seekers' per-table scores (``live``; a dead table's postings score only
+  that table), so mutation parity with a from-scratch rebuild holds
+  bit-exactly.
 
 Two interchangeable probe backends: ``"sorted"`` (binary search over each
 segment's hash-sorted run) and ``"bucket"`` (the Pallas ``bucket_probe``
@@ -21,12 +28,17 @@ and across mutation histories (tests/test_livelake.py).
 
 * ``rowjoin(rowkeys, mask, row_cap)`` — the numeric-postings-by-row probe of
   the correlation seeker (same fan-out over per-segment ``num_rowkey`` runs).
-* ``bloom(...)`` — the MC seeker's XASH superkey containment stage, routed
-  through the ``superkey_filter`` kernel package.
+* ``bloom(...)`` — the unfused MC seekers' XASH superkey containment stage,
+  routed through the ``superkey_filter`` kernel package (the fused MC runs
+  no superkey filter: ``row_holds`` validates exactly for less).
 * ``qcr(n_agree, n_all)`` — the correlation seeker's scoring epilogue,
   routed through the ``qcr_score`` kernel package.
-* ``member(sorted_keys, queries)`` — batched sorted-membership (the MC
-  validation join).
+* ``row_holds(pidx, q_hash)`` — whether a posting's row holds a value: one
+  gather of the row's cell hashes (``row_cells``) and a compare per column
+  (the fused MC validation; its cost follows the candidates, not the
+  value's count).
+* ``member(sorted_keys, queries)`` — batched sorted-membership (the
+  unfused MC validation join).
 
 The engine is a registered pytree: its arrays are leaves (so jitted seekers
 close over nothing) and its configuration — including the static per-segment
@@ -43,6 +55,7 @@ seekers per shard and sums the score matrices on the merge device.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -85,26 +98,52 @@ def probe_sorted_bounded(sorted_keys, n_real: int, queries, q_mask, cap):
     return pidx, valid, overflow
 
 
-def probe_sorted_capped(sorted_keys, n_real: int, queries, q_mask, cap,
-                        row_caps):
-    """``probe_sorted_bounded`` with *per-row* match capacities.
+class Window(NamedTuple):
+    """A flat probe window (``MatchEngine.window``): every (segment, query)
+    run of postings, laid end to end in segment-major order, so slot
+    ``ends[r] - cnt[r] + o`` holds posting ``lo[r] + o``."""
+    lo: jax.Array         # i32 [R]: each run's first posting
+    cnt: jax.Array        # i32 [R]: its postings (0 for a masked query)
+    ends: jax.Array       # i32 [R]: the inclusive prefix sum of ``cnt``
 
-    The fused executor (core/fused.py) batches seekers with different
-    (ladder-quantized) capacities into one launch: ``cap`` is the static
-    window width (the group maximum) while ``row_caps[i] <= cap`` restricts
-    row ``i`` to its own seeker's capacity, so per-seeker scores and
-    overflow stay bit-identical to a dedicated launch at that capacity.
-    Overflow is returned per row (callers segment-sum it by seeker)."""
-    lo = jnp.minimum(jnp.searchsorted(sorted_keys, queries, side="left"),
-                     n_real)
-    hi = jnp.minimum(jnp.searchsorted(sorted_keys, queries, side="right"),
-                     n_real)
-    lane = jnp.arange(cap)[None, :]
-    pidx = lo[:, None] + lane
-    valid = (pidx < hi[:, None]) & (lane < row_caps[:, None]) & q_mask[:, None]
-    pidx = jnp.clip(pidx, 0, sorted_keys.shape[0] - 1)
-    ovf_rows = jnp.where(q_mask, jnp.maximum(hi - lo - row_caps, 0), 0)
-    return pidx, valid, ovf_rows
+
+class Slots(NamedTuple):
+    """A range of slots of a flat window (``MatchEngine.slots``)."""
+    pidx: jax.Array       # i32: posting index (clipped on empty slots)
+    valid: jax.Array      # bool: a posting (tombstoned too) sits there
+    first: jax.Array      # bool: the first slot of its run
+
+
+def flat_slots(lo, cnt, ends, start, length: int, per_run=()):
+    """Slots ``start .. start + length - 1`` (``start`` may be negative) of
+    the runs ``[lo[r], lo[r] + cnt[r])`` laid end to end.  Returns (pidx
+    i32 unclipped, valid bool, first bool the first slot of its run, and
+    each ``[R]`` array of ``per_run`` at each slot's run).
+
+    No search per slot: the slots take the run that holds ``start``, and
+    each run that starts later in the range adds its difference from the
+    run before it at its first slot, which a prefix sum carries along.
+    Zero-count runs add theirs at the next run's start and hold no slot.
+    Integer values only (unsigned ones wrap and come back whole)."""
+    starts = ends - cnt
+    pos = starts - start
+    held = jnp.sum(pos <= 0) - 1              # the run that holds ``start``
+    at = jnp.where((pos > 0) & (pos < length), pos, length)
+
+    def spread(v):
+        prev = jnp.concatenate([jnp.zeros(1, v.dtype), v[:-1]])
+        base = jnp.where(held >= 0, v[jnp.maximum(held, 0)],
+                         jnp.zeros((), v.dtype))
+        return base + jnp.cumsum(jnp.zeros(length, v.dtype).at[at].add(
+            v - prev, mode="drop"))
+
+    slot = start + jnp.arange(length, dtype=jnp.int32)
+    pidx = slot + spread(lo - starts)
+    valid = (slot >= 0) & (slot < ends[-1])
+    first = jnp.zeros(length, bool).at[jnp.where(
+        (pos >= 0) & (pos < length) & (cnt > 0), pos, length)].set(
+        True, mode="drop")
+    return pidx, valid, first, tuple(spread(v) for v in per_run)
 
 
 def sorted_member(sorted_keys, queries):
@@ -198,6 +237,12 @@ class MatchEngine:
                              f"got {backend!r}")
         segs = store.segments
         seg_devs = [s.device_arrays(device) for s in segs]
+        # each segment's rows are as wide as its widest table: widen them
+        # all by repeating a cell of the same row, which changes no answer
+        width = max(d["row_cells"].shape[1] for d in seg_devs)
+        seg_devs = [dict(d, row_cells=jnp.concatenate(
+            [d["row_cells"]] + [d["row_cells"][:, :1]] *
+            (width - d["row_cells"].shape[1]), axis=1)) for d in seg_devs]
         dev = {k: jnp.concatenate([d[k] for d in seg_devs])
                for k in seg_devs[0]}
         seg_bounds, num_bounds = [], []
@@ -235,35 +280,6 @@ class MatchEngine:
         return self.config.backend
 
     # ------------------------------------------------------------ primitives
-    def _probe_segment(self, i: int, q_hash, q_mask, m_cap: int):
-        """One segment's (pidx, valid, overflow) window, globally indexed."""
-        start, length, n_real = self.config.seg_bounds[i]
-        if self.config.backend == "sorted":
-            keys = self.dev["hash"][start:start + length]
-            pidx, valid, ovf = probe_sorted_bounded(keys, n_real, q_hash,
-                                                    q_mask, m_cap)
-            return pidx + start, valid, ovf
-        nq = q_hash.shape[0]
-        q_block = min(256, nq)
-        hits = bucket_ops.probe(self.bucket_hashes[i], self.bucket_payload[i],
-                                q_hash, self.config.bucket_bits,
-                                use_kernel=True,
-                                interpret=self.config.interpret,
-                                q_block=q_block)          # [nq, W] payload|-1
-        hit = hits >= 0
-        count = jnp.sum(hit, axis=1)
-        n = self.dev["hash"].shape[0]
-        # postings are bucket-contiguous and hash-sorted within the segment,
-        # so the matched (globally-offset) payloads form the run
-        # [base, base + count): recover the window from the min payload
-        # instead of compacting the hit matrix
-        base = jnp.min(jnp.where(hit, hits, n), axis=1)
-        pidx = base[:, None] + jnp.arange(m_cap)[None, :]
-        valid = (jnp.arange(m_cap)[None, :] < count[:, None]) & q_mask[:, None]
-        pidx = jnp.clip(pidx, 0, n - 1)
-        overflow = jnp.sum(jnp.where(q_mask, jnp.maximum(count - m_cap, 0), 0))
-        return pidx, valid, overflow
-
     def probe(self, q_hash, q_mask, m_cap: int):
         """Postings window per query hash: (pidx, valid, overflow), fanned
         out over the segments ([nq, n_segments * m_cap]) with tombstoned
@@ -275,66 +291,95 @@ class MatchEngine:
         would be its own jit-cache entry — fragmenting the capacity-ladder
         buckets that make mutation serving retrace-free.  Compaction, not
         cap tuning, is the mechanism that bounds the fan-out cost."""
-        parts = [self._probe_segment(i, q_hash, q_mask, m_cap)
-                 for i in range(len(self.config.seg_bounds))]
-        if len(parts) == 1:
-            pidx, valid, ovf = parts[0]
-        else:
-            pidx = jnp.concatenate([p for p, _, _ in parts], axis=1)
-            valid = jnp.concatenate([v for _, v, _ in parts], axis=1)
-            ovf = sum(o for _, _, o in parts)
+        lo, cnt = self.runs(q_hash, q_mask)                 # [n_seg, nq]
+        lane = jnp.arange(m_cap)
+        nq = q_hash.shape[0]
+        pidx = (lo[:, :, None] + lane).transpose(1, 0, 2).reshape(nq, -1)
+        valid = (lane < cnt[:, :, None]).transpose(1, 0, 2).reshape(nq, -1)
+        pidx = jnp.clip(pidx, 0, self.dev["hash"].shape[0] - 1)
+        ovf = jnp.sum(jnp.maximum(cnt - m_cap, 0))
         if self.alive is not None:
             valid &= self.alive[self.dev["table"][pidx]]
         return pidx, valid, ovf
 
-    def _probe_segment_capped(self, i: int, q_hash, q_mask, m_cap: int,
-                              row_caps):
-        """``_probe_segment`` with per-row capacities (fused batching)."""
-        start, length, n_real = self.config.seg_bounds[i]
-        if self.config.backend == "sorted":
-            keys = self.dev["hash"][start:start + length]
-            pidx, valid, ovf = probe_sorted_capped(keys, n_real, q_hash,
-                                                   q_mask, m_cap, row_caps)
-            return pidx + start, valid, ovf
-        nq = q_hash.shape[0]
-        q_block = min(256, nq)
-        hits = bucket_ops.probe(self.bucket_hashes[i], self.bucket_payload[i],
-                                q_hash, self.config.bucket_bits,
-                                use_kernel=True,
-                                interpret=self.config.interpret,
-                                q_block=q_block)
-        hit = hits >= 0
-        count = jnp.sum(hit, axis=1)
-        n = self.dev["hash"].shape[0]
-        base = jnp.min(jnp.where(hit, hits, n), axis=1)
-        lane = jnp.arange(m_cap)[None, :]
-        pidx = base[:, None] + lane
-        valid = (lane < count[:, None]) & (lane < row_caps[:, None]) & \
-            q_mask[:, None]
-        pidx = jnp.clip(pidx, 0, n - 1)
-        ovf_rows = jnp.where(q_mask, jnp.maximum(count - row_caps, 0), 0)
-        return pidx, valid, ovf_rows
+    def runs(self, q_hash, q_mask):
+        """Each query's run of postings in each segment: (lo, cnt), i32
+        ``[n_segments, nq]``; ``lo`` indexes the concatenated arrays and
+        ``cnt`` is 0 for a masked query."""
+        los, cnts = [], []
+        for i, (start, length, n_real) in enumerate(self.config.seg_bounds):
+            if self.config.backend == "sorted":
+                keys = self.dev["hash"][start:start + length]
+                lo = jnp.minimum(jnp.searchsorted(keys, q_hash, side="left"),
+                                 n_real)
+                hi = jnp.minimum(jnp.searchsorted(keys, q_hash,
+                                                  side="right"), n_real)
+                lo, cnt = lo + start, hi - lo
+            else:
+                hits = bucket_ops.probe(
+                    self.bucket_hashes[i], self.bucket_payload[i], q_hash,
+                    self.config.bucket_bits, use_kernel=True,
+                    interpret=self.config.interpret,
+                    q_block=min(256, q_hash.shape[0]))
+                hit = hits >= 0
+                # payloads are global and a hash's postings contiguous:
+                # the run starts at the least payload
+                cnt = jnp.sum(hit, axis=1)
+                lo = jnp.min(jnp.where(hit, hits, self.dev["hash"].shape[0]),
+                             axis=1)
+            los.append(lo.astype(jnp.int32))
+            cnts.append(jnp.where(q_mask, cnt, 0).astype(jnp.int32))
+        return jnp.stack(los), jnp.stack(cnts)
 
-    def probe_capped(self, q_hash, q_mask, m_cap: int, row_caps):
-        """``probe`` with per-row match capacities: the fused executor
-        concatenates several seekers' padded query arrays into one batch and
-        probes them in a single launch; ``row_caps`` carries each row's own
-        (ladder-quantized) capacity so every seeker sees exactly the match
-        window its dedicated launch would have seen.  Returns per-row
-        overflow instead of a batch total, so callers can segment-sum it
-        back into per-seeker overflow counters."""
-        parts = [self._probe_segment_capped(i, q_hash, q_mask, m_cap,
-                                            row_caps)
-                 for i in range(len(self.config.seg_bounds))]
-        if len(parts) == 1:
-            pidx, valid, ovf_rows = parts[0]
-        else:
-            pidx = jnp.concatenate([p for p, _, _ in parts], axis=1)
-            valid = jnp.concatenate([v for _, v, _ in parts], axis=1)
-            ovf_rows = sum(o for _, _, o in parts)
-        if self.alive is not None:
-            valid &= self.alive[self.dev["table"][pidx]]
-        return pidx, valid, ovf_rows
+    def window(self, q_hash, q_mask) -> Window:
+        """The flat probe window: every posting of every (segment, query)
+        run, segment after segment.  Callers walk its slots in chunks
+        (``slots``), as many as the postings need, so a hot value holds as
+        many slots as it has postings and nothing is cut.  Within a run the
+        postings keep the index order (table, col, row), so a group-by key
+        repeats only in adjacent slots of one run."""
+        lo, cnt = self.runs(q_hash, q_mask)
+        cnt = cnt.reshape(-1)
+        return Window(lo.reshape(-1), cnt, jnp.cumsum(cnt))
+
+    def slots(self, win: Window, start, length: int, per_run=()):
+        """Slots ``start .. start + length - 1`` of ``win`` (``Slots``) and
+        each ``per_run`` array (``[R]``, see ``per_run``) at each slot's
+        run.  Tombstoned tables stay in ``valid``: whole tables die, so
+        their postings score only their own tables, which ``live`` zeroes
+        once per launch instead of once per slot."""
+        pidx, valid, first, vals = flat_slots(win.lo, win.cnt, win.ends,
+                                              start, length, per_run)
+        pidx = jnp.clip(pidx, 0, self.dev["hash"].shape[0] - 1)
+        return Slots(pidx, valid, first), vals
+
+    def live(self, scores):
+        """``scores`` (tables on the last axis) with tombstoned tables
+        zeroed."""
+        if self.alive is None:
+            return scores
+        return jnp.where(self.alive, scores, 0.0)
+
+    def per_run(self, per_query):
+        """A per-query array as a per-run one of ``window``'s runs."""
+        return jnp.tile(per_query, len(self.config.seg_bounds))
+
+    def overflow(self, win: Window, n_slots):
+        """Each query's postings past the window's first ``n_slots`` slots
+        (i32 [nq]): nonzero only where a count the host sized the walk from
+        was stale."""
+        ovf = jnp.clip(win.ends - n_slots, 0, win.cnt)
+        return ovf.reshape(len(self.config.seg_bounds), -1).sum(axis=0)
+
+    def row_holds(self, pidx, q_hash):
+        """Whether the row of the posting at ``pidx[i]`` holds a cell whose
+        hash is ``q_hash[i]``: one gather of the row's cell hashes
+        (``row_cells``, every column of the row) and a compare per column,
+        whatever the count of the value.  Tombstoned rows answer too: a
+        posting's row lies in its own table, so a dead row validates only
+        for that (dead) table."""
+        cells = self.dev["row_cells"][pidx]
+        return jnp.any(cells == q_hash[:, None], axis=1)
 
     def rowjoin(self, rowkeys, mask, row_cap: int):
         """Numeric-postings window per candidate rowkey: (nidx, nvalid),

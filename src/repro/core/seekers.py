@@ -9,9 +9,11 @@ zeroed *before* the expensive group-by / validation stages.
 
 All probing goes through ``MatchEngine.probe`` (core/match.py): the engine
 owns the device index and selects the searchsorted or Pallas bucket-probe
-backend; seekers never touch the raw hash array.  The MC bloom stage and the
-correlation scoring epilogue likewise route through the superkey_filter and
-qcr_score kernel packages via the engine.
+backend; seekers never touch the raw hash array.  The unfused MC programs'
+bloom stage and the correlation scoring epilogue likewise route through the
+superkey_filter and qcr_score kernel packages via the engine.  The fused MC
+validates each candidate row exactly from its cells' hashes and runs no
+superkey filter.
 
 Static capacities (``m_cap`` matches per value, ``row_cap`` numeric cells per
 row) keep shapes jit-stable; overflows are counted and surfaced, never
@@ -141,8 +143,10 @@ def mc_seeker(engine, tuple_hashes, init_col, qk_lo, qk_hi, *, m_cap,
 
     ok = valid
     for j in range(n_cols):                       # static, small
-        hj = tuple_hashes[:, j]
-        pj, vj, _ = engine.probe(hj, q_mask, m_cap)
+        # a truncated validation probe is flagged like the initiator's
+        pj, vj, oj = engine.probe(tuple_hashes[:, j],
+                                  q_mask & (init_col != j), m_cap)
+        ovf += oj
         tj = idx["table"][pj]
         rj = idx["row"][pj]
         rkj = tj.astype(jnp.int32) * row_stride + rj.astype(jnp.int32)
@@ -162,105 +166,164 @@ def mc_seeker(engine, tuple_hashes, init_col, qk_lo, qk_hi, *, m_cap,
 # --------------------------------------------------------------------------
 # Segmented (fused-batch) seeker variants — core/fused.py dispatches all
 # same-kind seekers of a plan (or of a whole serve_many batch) as ONE device
-# program: the padded query arrays are concatenated with per-row seeker ids
-# (``seg_id``) and per-row match capacities (``row_caps``, each seeker's own
-# ladder rung), probing goes through ``MatchEngine.probe_capped``, and the
-# group-by keys are prefixed with the seeker id so one scatter produces a
-# stacked [n_seekers, n_tables] score matrix.  Per-seeker contributions are
-# exactly the ones a dedicated launch would have produced (same valid
-# windows, same 0/1 integer sums), so each row of the stack is bit-identical
-# to the unfused seeker's output.  ``n_seekers`` is quantized to a power of
-# two by the caller so the batch stays retrace-free.
+# program: every posting of every query value lies in one flat window
+# (``MatchEngine.window``), and one scatter produces a stacked
+# [n_seekers, n_tables] score matrix.  SC and KW walk the batch's distinct
+# values once each and add to every seeker that holds the value; MC and C
+# concatenate their seekers' query arrays with per-row seeker ids
+# (``seg_id``) and prefix the group-by keys with them.  The program walks
+# the window in ``n_chunks`` chunks of ``chunk`` slots: ``n_chunks`` is an
+# operand the caller sizes from the summed match counts, so no value is cut
+# however hot and the program does not change with the count.  Every score is a sum of 0/1 contributions (or a QCR
+# ratio of such sums) over exactly the postings a dedicated exact launch
+# would see, so each row of the stack is bit-identical to the unfused
+# seeker's output wherever that one does not overflow.  ``n_seekers`` and
+# ``chunk`` are quantized to powers of two by the caller so batches stay
+# retrace-free.
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("m_cap", "n_seekers", "n_tables",
+def _starts_group(first, *keys):
+    """Whether each slot starts a key group within its run: a run keeps the
+    index order (table, col, row), so repeats of a key are adjacent.  Read
+    at index ``k`` against ``k - 1``, so a chunk reads one slot of the
+    chunk before it (index 0), and a group that spans two chunks counts
+    once."""
+    for k in keys:
+        first = first | (k != jnp.roll(k, 1))
+    return first
+
+
+def _seeker_overflow(engine, win, n_slots, seg_id, n_seekers):
+    ovf = engine.overflow(win, n_slots)
+    return jnp.zeros(n_seekers, ovf.dtype).at[seg_id].add(ovf, mode="drop")
+
+
+def _values_seg(engine, q_hash, q_mask, member, n_chunks, chunk, n_keys,
+                key_of):
+    """The group-by of the batched SC and KW seekers: the flat window over
+    the batch's distinct values, each walked once however many seekers hold
+    it.  The first slot of each group-by key (``key_of(pidx)``, < ``n_keys``)
+    in a value's run adds 1 to that key for every seeker that holds the
+    value (``member`` [nq, n_seekers] bool).  Returns (sums [n_keys,
+    n_seekers], overflow [n_seekers])."""
+    win = engine.window(q_hash, q_mask)
+    # each value's seekers as bits of 32-bit words, carried to its slots
+    # like any per-run value (no gather per slot)
+    nq, n_seekers = member.shape
+    n_words = -(-n_seekers // 32)
+    bit = jnp.arange(32, dtype=jnp.uint32)
+    words = jnp.sum(jnp.pad(member, ((0, 0), (0, 32 * n_words - n_seekers)))
+                    .reshape(nq, n_words, 32).astype(jnp.uint32) << bit,
+                    axis=2, dtype=jnp.uint32)
+    per_run = [engine.per_run(words[:, w]) for w in range(n_words)]
+
+    def chunk_sums(i, acc):
+        sl, held = engine.slots(win, i * chunk - 1, chunk + 1, per_run)
+        key = key_of(sl.pidx)
+        contrib = (sl.valid & _starts_group(sl.first, key))[1:]
+        add = jnp.concatenate([(w[1:, None] >> bit) & 1 for w in held],
+                              axis=1)[:, :n_seekers]
+        add = jnp.where(contrib[:, None], add, 0).astype(jnp.float32)
+        return acc.at[key[1:]].add(add, mode="drop")
+
+    sums = jax.lax.fori_loop(0, n_chunks, chunk_sums, jnp.zeros(
+        (n_keys, member.shape[1]), jnp.float32))
+    ovf = engine.overflow(win, n_chunks * chunk)
+    return sums, jnp.sum(jnp.where(member, ovf[:, None], 0), axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "n_tables",
                                              "max_cols"))
-def sc_seeker_seg(engine, q_hash, q_mask, seg_id, row_caps, *, m_cap,
-                  n_seekers, n_tables, max_cols):
-    """Batched ``sc_seeker``: one probe over the concatenated query rows,
-    one group-by into [n_seekers, n_tables].  Returns (scores, overflow
-    [n_seekers])."""
+def sc_seeker_seg(engine, q_hash, q_mask, member, n_chunks, *, chunk,
+                  n_tables, max_cols):
+    """Batched ``sc_seeker``: the batch's distinct values (``q_hash``) and
+    which of its seekers hold each (``member`` [nq, n_seekers]), grouped-by
+    into [n_seekers, n_tables].  Returns (scores, overflow [n_seekers])."""
     _mark_trace("SC_seg")
     idx = engine.dev
-    pidx, valid, ovf_rows = engine.probe_capped(q_hash, q_mask, m_cap,
-                                                row_caps)
-    t = idx["table"][pidx]
-    c = idx["col"][pidx]
-    contrib = valid & _first_occurrence(t, c, valid=valid)
-    flat = ((seg_id[:, None] * n_tables + t) * max_cols + c).reshape(-1)
-    scores = jnp.zeros(n_seekers * n_tables * max_cols, jnp.float32).at[
-        flat].add(contrib.reshape(-1).astype(jnp.float32), mode="drop")
-    ovf = jnp.zeros(n_seekers, ovf_rows.dtype).at[seg_id].add(ovf_rows,
-                                                              mode="drop")
-    return scores.reshape(n_seekers, n_tables, max_cols).max(axis=2), ovf
+    sums, ovf = _values_seg(
+        engine, q_hash, q_mask, member, n_chunks, chunk,
+        n_tables * max_cols, lambda p: idx["table"][p] * max_cols +
+        idx["col"][p])
+    scores = sums.reshape(n_tables, max_cols, -1).max(axis=1).T
+    return engine.live(scores), ovf
 
 
-@functools.partial(jax.jit, static_argnames=("m_cap", "n_seekers",
-                                             "n_tables"))
-def kw_seeker_seg(engine, q_hash, q_mask, seg_id, row_caps, *, m_cap,
-                  n_seekers, n_tables):
+@functools.partial(jax.jit, static_argnames=("chunk", "n_tables"))
+def kw_seeker_seg(engine, q_hash, q_mask, member, n_chunks, *, chunk,
+                  n_tables):
     """Batched ``kw_seeker`` (SC without the ColumnId group key)."""
     _mark_trace("KW_seg")
     idx = engine.dev
-    pidx, valid, ovf_rows = engine.probe_capped(q_hash, q_mask, m_cap,
-                                                row_caps)
-    t = idx["table"][pidx]
-    contrib = valid & _first_occurrence(t, valid=valid)
-    flat = (seg_id[:, None] * n_tables + t).reshape(-1)
-    scores = jnp.zeros(n_seekers * n_tables, jnp.float32).at[flat].add(
-        contrib.reshape(-1).astype(jnp.float32), mode="drop")
-    ovf = jnp.zeros(n_seekers, ovf_rows.dtype).at[seg_id].add(ovf_rows,
-                                                              mode="drop")
-    return scores.reshape(n_seekers, n_tables), ovf
+    sums, ovf = _values_seg(engine, q_hash, q_mask, member, n_chunks, chunk,
+                            n_tables, lambda p: idx["table"][p])
+    return engine.live(sums.T), ovf
 
 
-@functools.partial(jax.jit, static_argnames=("m_cap", "n_seekers", "n_tables",
-                                             "n_cols", "use_superkey",
-                                             "row_stride"))
-def mc_seeker_seg(engine, tuple_hashes, init_col, qk_lo, qk_hi, seg_id,
-                  row_caps, *, m_cap, n_seekers, n_tables, n_cols,
-                  row_stride=1 << 22, use_superkey=True, tuple_mask=None):
+@functools.partial(jax.jit, static_argnames=("chunk", "n_seekers",
+                                             "n_tables", "n_cols"))
+def mc_seeker_seg(engine, tuple_hashes, init_col, seg_id, n_chunks, *,
+                  chunk, n_seekers, n_tables, n_cols, tuple_mask=None):
     """Batched ``mc_seeker`` over the concatenated tuple blocks of all
-    same-width (``n_cols``) MC seekers; ``seg_id`` is per tuple.  The
-    matched-tuple counts are segment-summed by seeker after the per-tuple
-    dedupe, so each stacked row equals the dedicated launch's scores."""
+    same-width (``n_cols``) MC seekers; ``seg_id`` is per tuple.
+
+    The window holds every posting of each tuple's initiator value.  A
+    candidate row survives if it holds each other value of the tuple: one
+    gather of the row's cell hashes (``MatchEngine.row_holds``), exact at
+    any count of the other value.  No XASH superkey test runs before it:
+    every slot is computed on the device anyway, and the exact test costs
+    less than the filter would.  A tuple counts once per table: the
+    initiator run holds a table's postings in adjacent slots, so the run's
+    (tuple, table) groups are contiguous, and a group counts at its last
+    slot if any of its slots survived (a running maximum carries that
+    across chunks)."""
     _mark_trace("MC_seg")
     idx = engine.dev
     nt = tuple_hashes.shape[0]
     h0 = jnp.take_along_axis(tuple_hashes, init_col[:, None], 1)[:, 0]
     q_mask = _tuple_mask_or_ones(tuple_mask, nt)
-    pidx, valid, ovf_rows = engine.probe_capped(h0, q_mask, m_cap, row_caps)
-    t = idx["table"][pidx]
-    r = idx["row"][pidx]
-    if use_superkey:
-        valid &= engine.bloom(pidx, qk_lo, qk_hi)
-    rowkey = t.astype(jnp.int32) * row_stride + r.astype(jnp.int32)
+    win = engine.window(h0, q_mask)
+    # each run's tuple: its seeker and its values other than the initiator
+    per_run = [engine.per_run(seg_id)] + [
+        engine.per_run(jnp.take_along_axis(
+            tuple_hashes, (k + (init_col <= k))[:, None], 1)[:, 0])
+        for k in range(n_cols - 1)]
 
-    ok = valid
-    for j in range(n_cols):                       # static, small
-        hj = tuple_hashes[:, j]
-        pj, vj, _ = engine.probe_capped(hj, q_mask, m_cap, row_caps)
-        tj = idx["table"][pj]
-        rj = idx["row"][pj]
-        rkj = tj.astype(jnp.int32) * row_stride + rj.astype(jnp.int32)
-        rkj = jnp.where(vj, rkj, -1)
-        member = jnp.any(rowkey[:, :, None] == rkj[:, None, :], axis=-1)
-        ok &= member | (init_col == j)[:, None]
-    per_tt = jnp.zeros((nt * n_tables,), jnp.float32).at[
-        (jnp.arange(nt)[:, None] * n_tables + t).reshape(-1)].max(
-        ok.reshape(-1).astype(jnp.float32), mode="drop")
-    scores = jnp.zeros((n_seekers, n_tables), jnp.float32).at[seg_id].add(
-        per_tt.reshape(nt, n_tables), mode="drop")
-    ovf = jnp.zeros(n_seekers, ovf_rows.dtype).at[seg_id].add(ovf_rows,
-                                                              mode="drop")
-    return scores, ovf
+    def chunk_scores(i, carry):
+        acc, g_start, g_ok = carry
+        sl, vals = engine.slots(win, i * chunk - 1, chunk + 2, per_run)
+        t = idx["table"][sl.pidx]
+        group = _starts_group(sl.first, t)
+        ends_group = jnp.roll(group | ~sl.valid, -1)
+        core = slice(1, chunk + 1)
+        pidx, ok, t = sl.pidx[core], sl.valid[core], t[core]
+        group, ends_group = group[core], ends_group[core]
+        sid, *others = (v[core] for v in vals)
+        for h in others:
+            ok &= engine.row_holds(pidx, h)
+        s = i * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        start = jnp.maximum(g_start, jax.lax.cummax(
+            jnp.where(group, s, -1), axis=0))
+        last_ok = jnp.maximum(g_ok, jax.lax.cummax(
+            jnp.where(ok, s, -1), axis=0))
+        counts = sl.valid[core] & ends_group & (last_ok >= start)
+        acc = acc.at[sid * n_tables + t].add(counts.astype(jnp.float32),
+                                             mode="drop")
+        return acc, start[-1], last_ok[-1]
+
+    none = jnp.int32(-1)
+    scores, _, _ = jax.lax.fori_loop(0, n_chunks, chunk_scores, (jnp.zeros(
+        n_seekers * n_tables, jnp.float32), none, none))
+    return engine.live(scores.reshape(n_seekers, n_tables)), \
+        _seeker_overflow(engine, win, n_chunks * chunk, seg_id, n_seekers)
 
 
-@functools.partial(jax.jit, static_argnames=("m_cap", "row_cap", "n_seekers",
-                                             "n_tables", "max_cols",
-                                             "h_sample", "sampling",
-                                             "min_support", "row_stride"))
-def c_seeker_seg(engine, qj_hash, q_mask, q_bit, seg_id, row_caps, *, m_cap,
+@functools.partial(jax.jit, static_argnames=("chunk", "row_cap",
+                                             "n_seekers", "n_tables",
+                                             "max_cols", "h_sample",
+                                             "sampling", "min_support",
+                                             "row_stride"))
+def c_seeker_seg(engine, qj_hash, q_mask, q_bit, seg_id, n_chunks, *, chunk,
                  row_cap, n_seekers, n_tables, max_cols, h_sample,
                  row_stride=1 << 22, sampling="conv", min_support=3):
     """Batched ``c_seeker``: the QCR group-by key is prefixed with the
@@ -269,39 +332,38 @@ def c_seeker_seg(engine, qj_hash, q_mask, q_bit, seg_id, row_caps, *, m_cap,
     exactly the contributions the dedicated launch would have seen."""
     _mark_trace("C_seg")
     idx = engine.dev
-    pidx, valid, ovf_rows = engine.probe_capped(qj_hash, q_mask, m_cap,
-                                                row_caps)
-    t = idx["table"][pidx]
-    r = idx["row"][pidx]
-    cj = idx["col"][pidx]
-    rowkey = t.astype(jnp.int32) * row_stride + r.astype(jnp.int32)
-    rk_flat = rowkey.reshape(-1)
-
-    nidx, nvalid = engine.rowjoin(rk_flat, valid.reshape(-1), row_cap)
-
-    ntab = idx["num_table"][nidx]
-    ncol = idx["num_col"][nidx]
-    nquad = idx["num_quadrant"][nidx]
-    rank = idx["num_rank_conv" if sampling == "conv" else "num_rank_rand"][nidx]
-    nvalid &= rank < h_sample
-
-    qb = jnp.broadcast_to(q_bit[:, None], pidx.shape).reshape(-1)[:, None]
-    agree = (nquad == qb) & nvalid
-
-    segf = jnp.broadcast_to(seg_id[:, None], pidx.shape).reshape(-1)
+    win = engine.window(qj_hash, q_mask)
+    per_run = (engine.per_run(seg_id),
+               engine.per_run(q_bit.astype(jnp.int32)))
     dim = n_tables * max_cols * max_cols
-    key = segf[:, None] * dim + \
-        (ntab * max_cols + cj.reshape(-1)[:, None]) * max_cols + ncol
-    key = key.reshape(-1)
-    n_all = jnp.zeros(n_seekers * dim, jnp.float32).at[key].add(
-        nvalid.reshape(-1).astype(jnp.float32), mode="drop")
-    n_agree = jnp.zeros(n_seekers * dim, jnp.float32).at[key].add(
-        agree.reshape(-1).astype(jnp.float32), mode="drop")
+    rank_of = idx["num_rank_conv" if sampling == "conv" else "num_rank_rand"]
+
+    def chunk_sums(i, sums):
+        n_all, n_agree = sums
+        sl, (sid, qb) = engine.slots(win, i * chunk, chunk, per_run)
+        t = idx["table"][sl.pidx]
+        cj = idx["col"][sl.pidx]
+        rowkey = t.astype(jnp.int32) * row_stride + \
+            idx["row"][sl.pidx].astype(jnp.int32)
+        nidx, nvalid = engine.rowjoin(rowkey, sl.valid, row_cap)
+        ntab = idx["num_table"][nidx]
+        ncol = idx["num_col"][nidx]
+        nvalid &= rank_of[nidx] < h_sample
+        agree = (idx["num_quadrant"][nidx] == qb[:, None]) & nvalid
+        key = (sid[:, None] * dim +
+               (ntab * max_cols + cj[:, None]) * max_cols + ncol).reshape(-1)
+        return (n_all.at[key].add(nvalid.reshape(-1).astype(jnp.float32),
+                                  mode="drop"),
+                n_agree.at[key].add(agree.reshape(-1).astype(jnp.float32),
+                                    mode="drop"))
+
+    zeros = jnp.zeros(n_seekers * dim, jnp.float32)
+    n_all, n_agree = jax.lax.fori_loop(0, n_chunks, chunk_sums,
+                                       (zeros, zeros))
     qcr = engine.qcr(n_agree, n_all, min_support)
-    ovf = jnp.zeros(n_seekers, ovf_rows.dtype).at[seg_id].add(ovf_rows,
-                                                              mode="drop")
-    return qcr.reshape(n_seekers, n_tables, max_cols * max_cols).max(axis=2), \
-        ovf
+    scores = qcr.reshape(n_seekers, n_tables, max_cols * max_cols).max(axis=2)
+    return engine.live(scores), \
+        _seeker_overflow(engine, win, n_chunks * chunk, seg_id, n_seekers)
 
 
 # --------------------------------------------------------------------------
@@ -364,8 +426,10 @@ def mc_seeker_compact(engine, tuple_hashes, init_col, qk_lo, qk_hi, *, m_cap,
 
     ok = valid
     for j in range(n_cols):
-        hj = tuple_hashes[:, j]
-        pj, vj, _ = engine.probe(hj, q_mask, m_cap)
+        # a truncated validation probe is flagged like the initiator's
+        pj, vj, oj = engine.probe(tuple_hashes[:, j],
+                                  q_mask & (init_col != j), m_cap)
+        ovf += oj
         tj = idx["table"][pj]
         rj = idx["row"][pj]
         rkj = tj.astype(jnp.int32) * row_stride + rj.astype(jnp.int32)

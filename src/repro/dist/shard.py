@@ -54,6 +54,7 @@ import numpy as np
 
 from repro.core import seekers as seek
 from repro.core.executor import Executor
+from repro.core.fused import MAX_CHUNK
 from repro.core.index import _ceil_pow2, validate_row_stride
 from repro.core.match import EngineConfig, MatchEngine
 from repro.store.compact import (CompactionPolicy, compact_store,
@@ -420,8 +421,9 @@ GITTABLES_SCALE = dict(n_postings=1_400_000_000, n_numeric=350_000_000,
                        n_tables=1_500_000, max_cols=8, row_stride=1 << 8)
 
 
-def dryrun_discovery(multi_pod: bool = False, nq: int = 1024, m_cap: int = 64,
-                     n_tuples: int = 256, n_cols: int = 2, row_cap: int = 8):
+def dryrun_discovery(multi_pod: bool = False, nq: int = 1024,
+                     chunk: int = MAX_CHUNK, n_tuples: int = 256,
+                     n_cols: int = 2, row_cap: int = 8):
     """Lower + compile the per-shard fused seeker programs over a
     Gittables-scale shard (ShapeDtypeStructs, no allocation) sized for the
     production mesh.  Under table-axis MPMD sharding every device runs the
@@ -442,6 +444,7 @@ def dryrun_discovery(multi_pod: bool = False, nq: int = 1024, m_cap: int = 64,
            "table": sds((npad,), jnp.int32),
            "col": sds((npad,), jnp.int32),
            "row": sds((npad,), jnp.int32),
+           "row_cells": sds((npad, sc["max_cols"]), jnp.uint32),
            "sk_lo": sds((npad,), jnp.uint32),
            "sk_hi": sds((npad,), jnp.uint32),
            "quadrant": sds((npad,), jnp.int8),
@@ -460,28 +463,27 @@ def dryrun_discovery(multi_pod: bool = False, nq: int = 1024, m_cap: int = 64,
                        row_stride=sc["row_stride"])
     eng = MatchEngine(dev, None, None, cfg)
     nsp = 4                        # one fused group of 4 batched seekers
+    n_chunks = sds((), jnp.int32)  # the window's chunks: an operand
     fns = {
         "sc": (seek.sc_seeker_seg,
                (eng, sds((nq,), jnp.uint32), sds((nq,), jnp.bool_),
-                sds((nq,), jnp.int32), sds((nq,), jnp.int32)),
-               dict(m_cap=m_cap, n_seekers=nsp, n_tables=sc["n_tables"],
+                sds((nq, nsp), jnp.bool_), n_chunks),
+               dict(chunk=chunk, n_tables=sc["n_tables"],
                     max_cols=sc["max_cols"])),
         "kw": (seek.kw_seeker_seg,
                (eng, sds((nq,), jnp.uint32), sds((nq,), jnp.bool_),
-                sds((nq,), jnp.int32), sds((nq,), jnp.int32)),
-               dict(m_cap=m_cap, n_seekers=nsp, n_tables=sc["n_tables"])),
+                sds((nq, nsp), jnp.bool_), n_chunks),
+               dict(chunk=chunk, n_tables=sc["n_tables"])),
         "mc": (seek.mc_seeker_seg,
                (eng, sds((n_tuples, n_cols), jnp.uint32),
-                sds((n_tuples,), jnp.int32), sds((n_tuples,), jnp.uint32),
-                sds((n_tuples,), jnp.uint32), sds((n_tuples,), jnp.int32),
-                sds((n_tuples,), jnp.int32)),
-               dict(m_cap=m_cap, n_seekers=nsp, n_tables=sc["n_tables"],
-                    n_cols=n_cols, row_stride=sc["row_stride"])),
+                sds((n_tuples,), jnp.int32), sds((n_tuples,), jnp.int32),
+                n_chunks),
+               dict(chunk=chunk, n_seekers=nsp,
+                    n_tables=sc["n_tables"], n_cols=n_cols)),
         "c": (seek.c_seeker_seg,
               (eng, sds((nq,), jnp.uint32), sds((nq,), jnp.bool_),
-               sds((nq,), jnp.int8), sds((nq,), jnp.int32),
-               sds((nq,), jnp.int32)),
-              dict(m_cap=m_cap, row_cap=row_cap, n_seekers=nsp,
+               sds((nq,), jnp.int8), sds((nq,), jnp.int32), n_chunks),
+              dict(chunk=chunk, row_cap=row_cap, n_seekers=nsp,
                    n_tables=sc["n_tables"], max_cols=sc["max_cols"],
                    h_sample=256, row_stride=sc["row_stride"])),
     }

@@ -116,6 +116,7 @@ def compact_store(store: SegmentStore, policy: CompactionPolicy | None = None,
             superkey_hi=seg.superkey_hi, quadrant=seg.quadrant,
             rank_conv=seg.rank_conv, rank_rand=seg.rank_rand,
             num_perm=seg.num_perm, num_rowkey=seg.num_rowkey,
+            row_cells=seg.row_cells,
             bucket_bits=seg.bucket_bits, bucket_offsets=seg.bucket_offsets,
             n_real=seg.n_real, n_num=seg.n_num,
             tables=tuple(sorted(remap[t] for t in seg.tables)),

@@ -27,8 +27,8 @@ import numpy as np
 from repro.core import hashing
 from repro.core.index import (POSTING_KEYS, UnifiedIndex, _ceil_pow2,
                               bucket_offsets_for, concat_postings,
-                              numeric_view, sort_postings, table_postings,
-                              validate_row_stride)
+                              numeric_view, row_cells_view, sort_postings,
+                              table_postings, validate_row_stride)
 from repro.core.sketch import SketchConfig, sketch_tables
 
 SEG_PAD_MIN = 256          # smallest padded segment length (postings)
@@ -64,6 +64,9 @@ class Segment:
     rank_rand: np.ndarray
     num_perm: np.ndarray         # i32 [n_num_padded] segment-local indices
     num_rowkey: np.ndarray       # i32 [n_num_padded] sorted; int32-max tail
+    #: u32 [n_padded, width] each posting's row as its cells' hashes
+    #: (``row_cells_view``; width: the segment's widest table); zero tail
+    row_cells: np.ndarray
     bucket_bits: int
     bucket_offsets: np.ndarray   # i64 [2^bits + 1] over the real prefix
     n_real: int
@@ -103,7 +106,7 @@ class Segment:
     def storage_bytes(self) -> int:
         core = sum(getattr(self, k).nbytes for k in POSTING_KEYS)
         return core + self.num_perm.nbytes + self.num_rowkey.nbytes + \
-            self.bucket_offsets.nbytes
+            self.row_cells.nbytes + self.bucket_offsets.nbytes
 
     # ---------------------------------------------------------------- device
     def device_arrays(self, device=None) -> dict:
@@ -131,6 +134,7 @@ class Segment:
                 "quadrant": put(self.quadrant),
                 "rank_conv": put(self.rank_conv),
                 "rank_rand": put(self.rank_rand),
+                "row_cells": put(self.row_cells),
                 "num_rowkey": put(self.num_rowkey),
                 "num_table": put(self.table_id[p]),
                 "num_col": put(self.col_id[p]),
@@ -200,7 +204,8 @@ class Segment:
             superkey_lo=self.superkey_lo, superkey_hi=self.superkey_hi,
             quadrant=self.quadrant, rank_conv=self.rank_conv,
             rank_rand=self.rank_rand, num_perm=self.num_perm,
-            num_rowkey=num_rowkey, bucket_bits=self.bucket_bits,
+            num_rowkey=num_rowkey, row_cells=self.row_cells,
+            bucket_bits=self.bucket_bits,
             bucket_offsets=self.bucket_offsets, n_real=self.n_real,
             n_num=self.n_num, tables=self.tables,
             sketches=self.sketches)    # stride doesn't touch cell content
@@ -215,6 +220,17 @@ class Segment:
                 seg._dev[device] = dict(dev, num_rowkey=rk_dev)
         seg._dev_buckets = self._dev_buckets    # hash layout is unchanged
         return seg
+
+
+def _row_cells(parts: dict, n_padded: int) -> np.ndarray:
+    """``row_cells_view`` of a segment's postings, as wide as its widest
+    table, padded to ``n_padded`` rows."""
+    width = int(parts["col_id"].max(initial=0)) + 1
+    out = np.zeros((n_padded, width), np.uint32)
+    out[:len(parts["cell_hash"])] = row_cells_view(
+        parts["cell_hash"], parts["table_id"], parts["col_id"],
+        parts["row_id"], width)
+    return out
 
 
 def segment_from_arrays(parts: dict, *, bucket_bits: int, row_stride: int,
@@ -248,6 +264,7 @@ def segment_from_arrays(parts: dict, *, bucket_bits: int, row_stride: int,
         rank_rand=_pad_to(parts["rank_rand"], np_, PAD_RANK),
         num_perm=_pad_to(num_perm, nnp, 0),
         num_rowkey=_pad_to(num_rowkey, nnp, np.int32(2 ** 31 - 1)),
+        row_cells=_row_cells(parts, np_),
         bucket_bits=bucket_bits, bucket_offsets=bucket_offsets,
         n_real=n, n_num=n_num, tables=tables, sketches=sketches)
 

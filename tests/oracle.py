@@ -19,9 +19,11 @@ mirroring the executor's documented semantics:
   index-order tie-break, positive scores only), and the QCR division is done
   in float32 so fractional scores compare exactly against the device.
 
-Assumes the conformance lakes stay under the engine's static capacities
-(match counts within the m_cap ladder, numeric columns per row within
-row_cap) — the sweep in tests/test_oracle.py sizes its lakes accordingly.
+The fused path holds every posting of a value, however hot
+(tests/test_heavy_tail.py holds it to this oracle past 1,024 postings); the
+unfused seekers' dense windows stop at the m_cap ladder, and the correlation
+row join at ``row_cap`` numeric columns per row — the sweep in
+tests/test_oracle.py sizes its lakes accordingly.
 
 Used by tests/test_oracle.py (both probe backends vs this oracle) and by
 tests/test_query_cache.py (cache parity leans on the same ground truth).

@@ -272,7 +272,7 @@ def test_flight_recorder_and_metrics_end_to_end(tmp_path):
         # histogram
         for r in resps:
             shard = r.trace.find("shard:0")
-            assert shard is not None and shard.attrs["m_cap"] >= 1
+            assert shard is not None and shard.attrs["slots"] >= 1
             assert shard.t0 >= r.trace.find("execute").t0
         assert not any(n.startswith(("shard.probe_seconds", "exec.probe",
                                      "exec.dag", "exec.compile_seconds"))
@@ -440,11 +440,10 @@ def test_hash_misses_count_values_new_to_the_memo():
         _batch(_one_batch(DiscoveryServer(engine, trace=True, start=False),
                           queries)[0]) for _ in range(2))
     assert first.find("hash").attrs == {"values": hashed,
-                                        "misses": len(distinct),
-                                        "superkeys": len(set(mc_vals))}
-    assert second.find("hash").attrs == {"values": hashed, "misses": 0,
-                                         "superkeys": len(set(mc_vals))}
-    assert first.find("capacity").attrs == {"hashes": hashed}
+                                        "misses": len(distinct)}
+    assert second.find("hash").attrs == {"values": hashed, "misses": 0}
+    cap = first.find("capacity").attrs
+    assert cap["hashes"] == hashed and 0 < cap["matched"] <= cap["slots"]
     # the second batch's compiles come from the plan memo
     assert first.find("plan").attrs["plan_hits"] == 0
     assert second.find("plan").attrs["plan_hits"] == len(queries)

@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import seekers as seek
+from repro.core.fused import MAX_CHUNK
 from repro.core.match import EngineConfig, MatchEngine
 from repro.dist.shard import GITTABLES_SCALE
 from repro.kernels.bucket_probe import ops as bucket_ops
@@ -89,6 +90,7 @@ def engine_shapes(sds, n_postings: int, n_numeric: int, cfg):
         ("hash", u32), ("table", i32), ("col", i32), ("row", i32),
         ("sk_lo", u32), ("sk_hi", u32), ("quadrant", i8),
         ("rank_conv", i32), ("rank_rand", i32))}
+    dev["row_cells"] = sds((n_postings, cfg.max_cols), u32)
     dev.update({k: sds((n_numeric,), dt) for k, dt in (
         ("num_rowkey", i32), ("num_table", i32), ("num_col", i32),
         ("num_quadrant", i8), ("num_rank_conv", i32),
@@ -116,9 +118,32 @@ def test_sc_seeker_fits_one_chip_at_gittables_quarter(sds):
     nq = 1024
     compiled = seek.sc_seeker_seg.lower(
         eng, sds((nq,), jnp.uint32), sds((nq,), jnp.bool_),
-        sds((nq,), jnp.int32), sds((nq,), jnp.int32),
-        m_cap=64, n_seekers=4, n_tables=n_tables,
+        sds((nq, 4), jnp.bool_), sds((), jnp.int32),
+        chunk=MAX_CHUNK, n_tables=n_tables,
         max_cols=GITTABLES_SCALE["max_cols"]).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+
+
+def test_mc_seeker_fits_one_chip_at_webtables_zipf(sds):
+    """The fused MC program over the Zipf web-table lake (1,880,885
+    postings in a 2^21 run, 32,768 table slots) with a batch's 1,024 tuples
+    compiles and fits one chip's HBM: the validation's memory follows the
+    chunk, whatever the postings of the values."""
+    n, nnum = 1 << 21, 1 << 19
+    cfg = EngineConfig(backend="sorted", interpret=False, bucket_bits=12,
+                       bucket_widths=(), seg_bounds=((0, n, 1_880_885),),
+                       num_bounds=((0, nnum, 340_757),),
+                       n_tables=TABLE_SLOTS, max_cols=8, row_stride=256)
+    eng = engine_shapes(sds, n, nnum, cfg)
+    nt = 1024
+    u32, i32 = jnp.uint32, jnp.int32
+    compiled = seek.mc_seeker_seg.lower(
+        eng, sds((nt, 2), u32), sds((nt,), i32), sds((nt,), i32),
+        sds((), i32), chunk=MAX_CHUNK, n_seekers=16, n_tables=TABLE_SLOTS,
+        n_cols=2).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
@@ -140,18 +165,20 @@ def bucket_seeker_programs(sds):
     eng = engine_shapes(sds, base + delta, nbase + ndelta, cfg)
     nq, nt = 64, 16
     u32, i32, i8, b = jnp.uint32, jnp.int32, jnp.int8, jnp.bool_
-    common = dict(m_cap=512, n_seekers=2, n_tables=TABLE_SLOTS)
+    common = dict(chunk=MAX_CHUNK, n_seekers=2, n_tables=TABLE_SLOTS)
     return {
         "sc": (seek.sc_seeker_seg,
-               (eng, sds((nq,), u32), sds((nq,), b), sds((nq,), i32),
-                sds((nq,), i32)), dict(common, max_cols=MAX_COLS)),
+               (eng, sds((nq,), u32), sds((nq,), b), sds((nq, 2), b),
+                sds((), i32)),
+               dict(chunk=MAX_CHUNK, n_tables=TABLE_SLOTS,
+                    max_cols=MAX_COLS)),
         "mc": (seek.mc_seeker_seg,
-               (eng, sds((nt, 2), u32), sds((nt,), i32), sds((nt,), u32),
-                sds((nt,), u32), sds((nt,), i32), sds((nt,), i32)),
-               dict(common, n_cols=2, row_stride=64)),
+               (eng, sds((nt, 2), u32), sds((nt,), i32), sds((nt,), i32),
+                sds((), i32)),
+               dict(common, n_cols=2)),
         "c": (seek.c_seeker_seg,
               (eng, sds((nq,), u32), sds((nq,), b), sds((nq,), i8),
-               sds((nq,), i32), sds((nq,), i32)),
+               sds((nq,), i32), sds((), i32)),
               dict(common, row_cap=8, max_cols=MAX_COLS, h_sample=256,
                    row_stride=64)),
     }
