@@ -9,9 +9,11 @@ The fused path collapses a plan (or a whole ``serve_many`` batch) to
 
 1. **Batched seeker dispatch** — all same-kind seekers, across every plan in
    the batch, are concatenated into one padded query array with per-row
-   seeker ids, probed once into one flat window that holds every posting of
-   every query value (``MatchEngine.window``) and grouped-by into a stacked
-   ``[n_seekers, n_tables]`` score matrix (seekers.py ``*_seeker_seg``).
+   seeker ids (SC and KW: the batch's distinct values, each with the id of
+   its holder set, ``_distinct``), probed once into one flat window that
+   holds every posting of every query value (``MatchEngine.window``) and
+   grouped-by into a stacked ``[n_seekers, n_tables]`` score matrix
+   (seekers.py ``*_seeker_seg``).
    The program walks the window in chunks of slots; how many comes from
    ONE ``host_counts`` call over every seeker's hashes (the group's summed
    match counts), so no value is cut however many postings it has.
@@ -25,8 +27,9 @@ Bit-identity with the unfused executor rests on two invariants:
 
 * the flat window holds every posting of each seeker's query values, as an
   exact dedicated launch would, and every seeker score is a sum / max of
-  0-or-1 float contributions (or a QCR ratio of such sums), so the stacked
-  rows equal the dedicated launches bit-for-bit;
+  0-or-1 float contributions (or a QCR ratio of such sums; SC and KW sum
+  per holder set and then over a seeker's sets, integers all the way), so
+  the stacked rows equal the dedicated launches bit-for-bit;
 * a seeker run under the optimizer's threaded ``allowed`` mask equals
   ``where(allowed, unrestricted_scores, 0)`` followed by the same top-k —
   the mask is constant per table and is ANDed into contributions *before* a
@@ -51,6 +54,7 @@ from __future__ import annotations
 import functools
 import time
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -221,6 +225,10 @@ MIN_CHUNK, MAX_CHUNK = 1 << 10, 1 << 14
 #: low; 16 seekers hold one of each kind for a whole batch of the server's
 #: default policy
 MIN_WIDTH, MIN_SEEKERS = 256, 16
+#: an SC or KW launch of ``nsp`` padded seekers counts by up to
+#: ``SETS_PER_SEEKER * nsp`` holder sets (the seekers that hold a value): a
+#: shape the seeker count already fixes, so the sets add no program
+SETS_PER_SEEKER = 4
 
 
 def _walk(matched: int) -> tuple:
@@ -229,15 +237,16 @@ def _walk(matched: int) -> tuple:
     return chunk, -(-matched // chunk)
 
 
-def _hash_tasks(ex, groups) -> dict:
+def _hash_tasks(ex, groups) -> tuple:
     """Hash every pending seeker's query values (through the executor's
     memoized value-hash cache) and size every group's flat window from one
     batched ``host_counts`` lookup over the concatenated hash arrays.
     Returns each group's walk of its window, ``(chunk, n_chunks)``, keyed
-    like ``groups`` (a tuple of per-shard walks on a sharded lake)."""
+    like ``groups`` (a tuple of per-shard walks on a sharded lake), and each
+    SC or KW group's values as its window lays them (``_distinct``)."""
     tasks = [t for g in groups.values() for t in g]
     if not tasks:
-        return {}
+        return {}, {}
     rec = otrace.current()
     reqs = []
     with rec.span("hash") as sp:
@@ -289,12 +298,16 @@ def _hash_tasks(ex, groups) -> dict:
                 # the window holds each tuple's initiator postings
                 pick = np.arange(t.nt) * t.spec.n_cols + t.init_col
             t.counts = (per[:, cut] if n_shards else counts[cut])[..., pick]
-        walks, matched = {}, 0
+        walks, values, matched = {}, {}, 0
         for key, g in groups.items():
             c = np.concatenate([t.counts for t in g], axis=-1)
             if key[0] in ("SC", "KW"):
-                # the window holds each distinct value of the group once
-                c = c[..., _distinct(g)[2]]
+                # the window holds each distinct value of the group once,
+                # or once per holder where its holder set does not fit
+                values[key] = _distinct(
+                    g, c.reshape(-1, c.shape[-1]).sum(axis=0),
+                    SETS_PER_SEEKER * _n_seekers(len(g)))
+                c = c[..., values[key].pos]
             c = c.sum(axis=-1)
             matched += int(c.sum())
             walks[key] = tuple(_walk(int(m)) for m in c) if n_shards \
@@ -303,19 +316,73 @@ def _hash_tasks(ex, groups) -> dict:
             sp.set("hashes", len(all_h))
             sp.set("slots", sum(_slots(w) for w in walks.values()))
             sp.set("matched", matched)
-    return walks
+    return walks, values
 
 
-def _distinct(tasks) -> tuple:
-    """The distinct values of a group of SC or KW seekers: (their hashes,
-    ``[n, len(tasks)]`` which seekers hold each, and each one's first place
-    in the seekers' values laid end to end)."""
+class _Values(NamedTuple):
+    """An SC or KW group's values as its window lays them (``_distinct``)."""
+    h: np.ndarray         # u32 [n]: each laid value's hash
+    set_id: np.ndarray    # i32 [n]: its holder set, a row of ``sets``
+    sets: np.ndarray      # bool [n_sets, len(tasks)]: which seekers hold it
+    pos: np.ndarray       # [n]: its place in the seekers' values end to end
+    expanded: int         # values laid once per holder (set did not fit)
+
+
+def _distinct(tasks, counts, max_sets) -> _Values:
+    """The distinct values of a group of SC or KW seekers, each with the
+    set of seekers that hold it.  ``counts`` holds the postings of the
+    seekers' values laid end to end.  A launch counts by at most
+    ``max_sets`` sets: past that, the sets whose values would cost the
+    fewest slots walked once per holder do not fit, and each of their
+    values is laid once per holder, each copy held by that seeker alone
+    (exact: every copy adds to one seeker what the value adds to it)."""
     h = np.concatenate([t.h for t in tasks])
     u, first, inv = np.unique(h, return_index=True, return_inverse=True)
-    member = np.zeros((len(u), len(tasks)), bool)
-    member[inv.reshape(-1), np.repeat(np.arange(len(tasks)),
-                                      [len(t.h) for t in tasks])] = True
-    return u, member, first
+    inv = inv.reshape(-1)
+    n = len(tasks)
+    # each value's holders as the bits of 64-bit words; a seeker's hashes
+    # are distinct, so OR-ing its bit in is adding it
+    seeker = np.repeat(np.arange(n), [len(t.h) for t in tasks])
+    bits = np.zeros((len(h), -(-n // 64)), "<u8")
+    bits[np.arange(len(h)), seeker // 64] = \
+        np.uint64(1) << (seeker % 64).astype(np.uint64)
+    words = np.zeros((len(u), bits.shape[1]), "<u8")
+    np.add.at(words, inv, bits)
+    set_of, rep = _unique_rows(words)
+    spill = np.zeros(len(u), bool)
+    if len(rep) > max_sets:
+        size = np.unpackbits(words[rep].view(np.uint8), axis=1).sum(axis=1)
+        cost = (size - 1) * np.bincount(set_of, weights=counts[first],
+                                        minlength=len(rep))
+        multi = np.flatnonzero(size > 1)
+        # room for the singletons every spilled copy is held by
+        out = np.zeros(len(rep), bool)
+        out[multi[np.argsort(-cost[multi], kind="stable")[max_sets - n:]]] = \
+            True
+        spill = out[set_of]
+        copies = spill[inv]
+        laid = np.concatenate([np.flatnonzero(~spill), inv[copies]])
+        words = np.concatenate([words[~spill], bits[copies]])
+        set_of, rep = _unique_rows(words)
+        u, first = u[laid], first[laid]
+    sets = np.unpackbits(words[rep].view(np.uint8), axis=1,
+                         bitorder="little")[:, :n].astype(bool)
+    return _Values(u, set_of.astype(np.int32), sets, first,
+                   int(spill.sum()))
+
+
+def _unique_rows(words) -> tuple:
+    """(each row's index among the distinct rows of ``words``, one row of
+    each): a 1-D ``np.unique`` per column (``axis=0`` sorts rows several
+    times slower)."""
+    _, ids = np.unique(words[:, 0], return_inverse=True)
+    for w in words.T[1:]:
+        _, ids = np.unique(ids.reshape(-1) * len(words) + np.unique(
+            w, return_inverse=True)[1].reshape(-1), return_inverse=True)
+    ids = ids.reshape(-1)
+    rep = np.zeros(len(words) and ids.max() + 1, np.int64)
+    rep[ids] = np.arange(len(words))
+    return ids, rep
 
 
 def _slots(walk) -> int:
@@ -333,14 +400,25 @@ def _pow2(n: int, lo: int) -> int:
     return _pow2_at_least(max(n, 1), lo=lo, hi=1 << 30)
 
 
-def _launch_group(ex, key, tasks, walk, failed=None):
+def _n_seekers(n: int) -> int:
+    """The padded seeker count of a launch of ``n``: a serving batch's
+    per-kind seeker count varies with every batch composition; padding the
+    stacked output to at least ``MIN_SEEKERS`` rows collapses it (and with
+    it the DAG program's group-matrix input shapes) onto a couple of
+    buckets, so reshuffled batches stop retracing.  The padding itself is
+    dead rows in a [nsp, n_tables] matrix."""
+    return _pow2(n, lo=MIN_SEEKERS)
+
+
+def _launch_group(ex, key, tasks, walk, values=None, failed=None):
     """Dispatch one seeker group as a single device program.  Returns
     (scores [n_seekers_p, n_tables], overflow [n_seekers_p]) — both lazy.
     ``tasks`` are the deduped head tasks of the group (run_fused collapses
     identical specs before hashing).
 
     ``walk`` is the window's ``(chunk, n_chunks)`` (``_hash_tasks``; a tuple
-    of per-shard walks on a sharded lake).
+    of per-shard walks on a sharded lake), and ``values`` an SC or KW
+    group's laid values with their holder sets (``_distinct``).
 
     Sharded executors (``ex.engines``) dispatch the same batched program
     once per shard — same query operands, per-shard windows — and
@@ -358,13 +436,7 @@ def _launch_group(ex, key, tasks, walk, failed=None):
     for i, t in enumerate(tasks):
         t.row = i
     kind = key[0]
-    # lo=8: a serving batch's per-kind seeker count varies with every batch
-    # composition; padding the stacked output to at least 8 rows collapses
-    # nsp (and with it the DAG program's group-matrix input shapes) onto a
-    # couple of buckets, so reshuffled batches stop retracing.  The padding
-    # itself is dead rows in a [nsp, n_tables] matrix — negligible next to
-    # the probe work, and single-plan latency is unaffected (measured).
-    nsp = _pow2(len(tasks), lo=MIN_SEEKERS)
+    nsp = _n_seekers(len(tasks))
 
     if kind == "MC":
         n_cols = key[1]
@@ -393,19 +465,21 @@ def _launch_group(ex, key, tasks, walk, failed=None):
                 n_seekers=nsp, n_tables=ex.n_tables, n_cols=n_cols,
                 tuple_mask=tmask)
     elif kind in ("SC", "KW"):
-        h, member, _ = _distinct(tasks)
-        width = _pow2(len(h), lo=MIN_WIDTH)
+        n = len(values.h)
+        width = _pow2(n, lo=MIN_WIDTH)
         qh = np.full(width, PAD_SENTINEL, np.uint32)
-        qh[:len(h)] = h
-        qm = np.arange(width) < len(h)
-        mem = np.zeros((width, nsp), bool)
-        mem[:len(h), :len(tasks)] = member
+        qh[:n] = values.h
+        qm = np.arange(width) < n
+        set_id = np.zeros(width, np.int32)
+        set_id[:n] = values.set_id
+        sets = np.zeros((SETS_PER_SEEKER * nsp, nsp), bool)
+        sets[:len(values.sets), :len(tasks)] = values.sets
         fn, opts = (seek.sc_seeker_seg, dict(max_cols=ex.max_cols)) \
             if kind == "SC" else (seek.kw_seeker_seg, {})
 
         def dispatch(eng, walk):
-            return fn(eng, qh, qm, mem, np.int32(walk[1]), chunk=walk[0],
-                      n_tables=ex.n_tables, **opts)
+            return fn(eng, qh, qm, set_id, sets, np.int32(walk[1]),
+                      chunk=walk[0], n_tables=ex.n_tables, **opts)
     else:
         total = sum(len(t.h) for t in tasks)
         width = _pow2(total, lo=MIN_WIDTH)
@@ -610,7 +684,7 @@ def run_fused(ex, plans, optimize=True, cost_model=None, cache=None):
     for h in heads.values():
         h.group_key = _group_key(h.spec)
         groups.setdefault(h.group_key, []).append(h)
-    walks = _hash_tasks(ex, groups)
+    walks, values = _hash_tasks(ex, groups)
     group_out: dict[tuple, tuple] = {}
     launch_seconds: dict[tuple, float] = {}
     failed_shards: set = set()
@@ -622,7 +696,11 @@ def run_fused(ex, plans, optimize=True, cost_model=None, cache=None):
         t0 = time.perf_counter()
         with rec.span("probe:" + kind_name, seekers=len(groups[key]),
                       slots=_slots(walks[key])) as sp:
+            if rec.enabled and key in values:
+                sp.set("sets", len(values[key].sets))
+                sp.set("expanded", values[key].expanded)
             group_out[key] = _launch_group(ex, key, groups[key], walks[key],
+                                           values.get(key),
                                            failed=failed_shards)
         launch_seconds[key] = time.perf_counter() - t0
         if sum(seek.TRACE_COUNTS.values()) > tr0:

@@ -169,17 +169,18 @@ def mc_seeker(engine, tuple_hashes, init_col, qk_lo, qk_hi, *, m_cap,
 # program: every posting of every query value lies in one flat window
 # (``MatchEngine.window``), and one scatter produces a stacked
 # [n_seekers, n_tables] score matrix.  SC and KW walk the batch's distinct
-# values once each and add to every seeker that holds the value; MC and C
+# values once each, count per holder set (the seekers that hold a value)
+# and turn set counts into seeker counts with one product; MC and C
 # concatenate their seekers' query arrays with per-row seeker ids
 # (``seg_id``) and prefix the group-by keys with them.  The program walks
 # the window in ``n_chunks`` chunks of ``chunk`` slots: ``n_chunks`` is an
 # operand the caller sizes from the summed match counts, so no value is cut
-# however hot and the program does not change with the count.  Every score is a sum of 0/1 contributions (or a QCR
-# ratio of such sums) over exactly the postings a dedicated exact launch
-# would see, so each row of the stack is bit-identical to the unfused
-# seeker's output wherever that one does not overflow.  ``n_seekers`` and
-# ``chunk`` are quantized to powers of two by the caller so batches stay
-# retrace-free.
+# however hot and the program does not change with the count.  Every score
+# is a sum of 0/1 contributions (or a QCR ratio of such sums) over exactly
+# the postings a dedicated exact launch would see, so each row of the stack
+# is bit-identical to the unfused seeker's output wherever that one does
+# not overflow.  ``n_seekers`` and ``chunk`` are quantized to powers of two
+# by the caller so batches stay retrace-free.
 # --------------------------------------------------------------------------
 
 def _starts_group(first, *keys):
@@ -198,66 +199,69 @@ def _seeker_overflow(engine, win, n_slots, seg_id, n_seekers):
     return jnp.zeros(n_seekers, ovf.dtype).at[seg_id].add(ovf, mode="drop")
 
 
-def _values_seg(engine, q_hash, q_mask, member, n_chunks, chunk, n_keys,
-                key_of):
+def _values_seg(engine, q_hash, q_mask, set_id, sets, n_chunks, chunk,
+                n_keys, key_of):
     """The group-by of the batched SC and KW seekers: the flat window over
     the batch's distinct values, each walked once however many seekers hold
-    it.  The first slot of each group-by key (``key_of(pidx)``, < ``n_keys``)
-    in a value's run adds 1 to that key for every seeker that holds the
-    value (``member`` [nq, n_seekers] bool).  Returns (sums [n_keys,
-    n_seekers], overflow [n_seekers])."""
+    it.  Each value carries the id of its holder set (``set_id`` [nq]; row
+    ``set_id`` of ``sets`` [n_sets, n_seekers] bool says which seekers hold
+    it) to its slots like any per-run value.  The first slot of each
+    group-by key (``key_of(pidx)``, < ``n_keys``) in a value's run adds 1
+    at (set, key), one scalar per slot, and one exact product turns the
+    [n_sets, n_keys] counts into each seeker's: the counts are integers
+    under 2**24, ``sets`` is 0/1, and HIGHEST keeps every bit of the
+    counts.  Returns (sums [n_seekers, n_keys], overflow [n_seekers])."""
+    n_sets = sets.shape[0]
+    if n_sets * n_keys >= 2 ** 31:
+        raise ValueError(f"{n_sets} holder sets x {n_keys} group-by keys "
+                         f"do not fit an int32 index")
     win = engine.window(q_hash, q_mask)
-    # each value's seekers as bits of 32-bit words, carried to its slots
-    # like any per-run value (no gather per slot)
-    nq, n_seekers = member.shape
-    n_words = -(-n_seekers // 32)
-    bit = jnp.arange(32, dtype=jnp.uint32)
-    words = jnp.sum(jnp.pad(member, ((0, 0), (0, 32 * n_words - n_seekers)))
-                    .reshape(nq, n_words, 32).astype(jnp.uint32) << bit,
-                    axis=2, dtype=jnp.uint32)
-    per_run = [engine.per_run(words[:, w]) for w in range(n_words)]
+    per_run = [engine.per_run(set_id)]
 
-    def chunk_sums(i, acc):
-        sl, held = engine.slots(win, i * chunk - 1, chunk + 1, per_run)
+    def chunk_counts(i, acc):
+        sl, (sid,) = engine.slots(win, i * chunk - 1, chunk + 1, per_run)
         key = key_of(sl.pidx)
         contrib = (sl.valid & _starts_group(sl.first, key))[1:]
-        add = jnp.concatenate([(w[1:, None] >> bit) & 1 for w in held],
-                              axis=1)[:, :n_seekers]
-        add = jnp.where(contrib[:, None], add, 0).astype(jnp.float32)
-        return acc.at[key[1:]].add(add, mode="drop")
+        return acc.at[sid[1:] * n_keys + key[1:]].add(
+            contrib.astype(jnp.float32), mode="drop")
 
-    sums = jax.lax.fori_loop(0, n_chunks, chunk_sums, jnp.zeros(
-        (n_keys, member.shape[1]), jnp.float32))
+    counts = jax.lax.fori_loop(0, n_chunks, chunk_counts,
+                               jnp.zeros(n_sets * n_keys, jnp.float32))
+    sums = jnp.dot(sets.T.astype(jnp.float32), counts.reshape(n_sets, n_keys),
+                   precision=jax.lax.Precision.HIGHEST)
     ovf = engine.overflow(win, n_chunks * chunk)
-    return sums, jnp.sum(jnp.where(member, ovf[:, None], 0), axis=0)
+    per_set = jnp.zeros(n_sets, ovf.dtype).at[set_id].add(ovf)
+    return sums, jnp.sum(jnp.where(sets, per_set[:, None], 0), axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "n_tables",
                                              "max_cols"))
-def sc_seeker_seg(engine, q_hash, q_mask, member, n_chunks, *, chunk,
+def sc_seeker_seg(engine, q_hash, q_mask, set_id, sets, n_chunks, *, chunk,
                   n_tables, max_cols):
-    """Batched ``sc_seeker``: the batch's distinct values (``q_hash``) and
-    which of its seekers hold each (``member`` [nq, n_seekers]), grouped-by
-    into [n_seekers, n_tables].  Returns (scores, overflow [n_seekers])."""
+    """Batched ``sc_seeker``: the batch's distinct values (``q_hash``), the
+    holder set of each (``set_id`` into ``sets`` [n_sets, n_seekers]),
+    grouped-by into [n_seekers, n_tables].  Returns (scores, overflow
+    [n_seekers])."""
     _mark_trace("SC_seg")
     idx = engine.dev
     sums, ovf = _values_seg(
-        engine, q_hash, q_mask, member, n_chunks, chunk,
+        engine, q_hash, q_mask, set_id, sets, n_chunks, chunk,
         n_tables * max_cols, lambda p: idx["table"][p] * max_cols +
         idx["col"][p])
-    scores = sums.reshape(n_tables, max_cols, -1).max(axis=1).T
+    scores = sums.reshape(-1, n_tables, max_cols).max(axis=2)
     return engine.live(scores), ovf
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "n_tables"))
-def kw_seeker_seg(engine, q_hash, q_mask, member, n_chunks, *, chunk,
+def kw_seeker_seg(engine, q_hash, q_mask, set_id, sets, n_chunks, *, chunk,
                   n_tables):
-    """Batched ``kw_seeker`` (SC without the ColumnId group key)."""
+    """Batched ``kw_seeker`` (SC without the ColumnId group key), on the
+    same holder-set operands."""
     _mark_trace("KW_seg")
     idx = engine.dev
-    sums, ovf = _values_seg(engine, q_hash, q_mask, member, n_chunks, chunk,
-                            n_tables, lambda p: idx["table"][p])
-    return engine.live(sums.T), ovf
+    sums, ovf = _values_seg(engine, q_hash, q_mask, set_id, sets, n_chunks,
+                            chunk, n_tables, lambda p: idx["table"][p])
+    return engine.live(sums), ovf
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "n_seekers",

@@ -54,7 +54,7 @@ import numpy as np
 
 from repro.core import seekers as seek
 from repro.core.executor import Executor
-from repro.core.fused import MAX_CHUNK
+from repro.core.fused import MAX_CHUNK, SETS_PER_SEEKER
 from repro.core.index import _ceil_pow2, validate_row_stride
 from repro.core.match import EngineConfig, MatchEngine
 from repro.store.compact import (CompactionPolicy, compact_store,
@@ -464,15 +464,16 @@ def dryrun_discovery(multi_pod: bool = False, nq: int = 1024,
     eng = MatchEngine(dev, None, None, cfg)
     nsp = 4                        # one fused group of 4 batched seekers
     n_chunks = sds((), jnp.int32)  # the window's chunks: an operand
+    holder_sets = sds((SETS_PER_SEEKER * nsp, nsp), jnp.bool_)
     fns = {
         "sc": (seek.sc_seeker_seg,
                (eng, sds((nq,), jnp.uint32), sds((nq,), jnp.bool_),
-                sds((nq, nsp), jnp.bool_), n_chunks),
+                sds((nq,), jnp.int32), holder_sets, n_chunks),
                dict(chunk=chunk, n_tables=sc["n_tables"],
                     max_cols=sc["max_cols"])),
         "kw": (seek.kw_seeker_seg,
                (eng, sds((nq,), jnp.uint32), sds((nq,), jnp.bool_),
-                sds((nq, nsp), jnp.bool_), n_chunks),
+                sds((nq,), jnp.int32), holder_sets, n_chunks),
                dict(chunk=chunk, n_tables=sc["n_tables"])),
         "mc": (seek.mc_seeker_seg,
                (eng, sds((n_tuples, n_cols), jnp.uint32),

@@ -11,12 +11,14 @@ tuple of a cold initiator and a hot partner, which the dense window
 answered wrong with no overflow flag.  Also the primitives themselves and
 the unfused MC seekers' overflow for a truncated validation probe.
 """
+import jax
 import numpy as np
 import pytest
 
 import blend
 from repro.core.executor import Executor
 from repro.core import fused
+from repro.core import seekers as seek
 from repro.core.index import build_index
 from repro.core.lake import DataLake, Table
 from repro.core.match import flat_slots
@@ -149,6 +151,23 @@ def test_live_lake_after_dropping_a_hot_table(backend):
         assert_oracle(resp, oracle(now), now.n_tables)
 
 
+def holders(ex, lists) -> dict:
+    """Each distinct value hash of a batch's seekers -> the seekers (their
+    places in ``lists``) that hold it."""
+    out: dict = {}
+    for i, vals in enumerate(lists):
+        for h in ex._hash_many(vals):
+            out.setdefault(int(h), set()).add(i)
+    return out
+
+
+def launch_spans(rec, kind):
+    """The ``capacity`` and ``probe:<kind>`` spans of a recorded batch."""
+    spans = [s for root in rec.roots for s in root.walk()]
+    return (next(s for s in spans if s.name == "capacity"),
+            next(s for s in spans if s.name == f"probe:{kind}"))
+
+
 @pytest.mark.parametrize("n_seekers,backend", [(3, "sorted"),
                                                (3, "bucket"),
                                                (40, "sorted")])
@@ -158,25 +177,86 @@ def test_seekers_sharing_values_walk_each_value_once(kind, n_seekers,
     """Seekers of one batch share the hot value and some others: the
     window holds each distinct value once (``capacity.matched`` is the
     union's postings, not the seekers' sum), and every seeker still gets
-    its own exact answer, past 32 seekers too (two words of bits)."""
+    its own exact answer, past 32 seekers too.  The ``probe`` span counts
+    the batch's distinct holder sets, none of them expanded."""
     make, oracle = {"SC": (blend.sc, oracle_sc), "KW": (blend.kw, oracle_kw)}[
         kind]
-    sets = [[HOT, f"v{i % 7}", LAKE.tables[i % N_TABLES].columns[0][i + 1]]
-            for i in range(n_seekers)]
+    lists = [[HOT, f"v{i % 7}", LAKE.tables[i % N_TABLES].columns[0][i + 1]]
+             for i in range(n_seekers)]
     session = blend.connect(LAKE, live=True, backend=backend,
                             interpret=True)
     rec = otrace.Recorder()
     with otrace.recording(rec):
-        resps = serve(session, [make(v, k=K) for v in sets])
-    for v, resp in zip(sets, resps):
+        resps = serve(session, [make(v, k=K) for v in lists])
+    for v, resp in zip(lists, resps):
         assert_oracle(resp, oracle(LAKE, v), LAKE.n_tables)
-    cap = next(s for root in rec.roots for s in root.walk()
-               if s.name == "capacity")
+    cap, probe = launch_spans(rec, kind)
     ex = session.executor
-    union = np.unique(ex._hash_many([x for v in sets for x in v]))
-    each = sum(int(ex.index.host_counts(ex._hashed(v)).sum()) for v in sets)
+    union = np.unique(ex._hash_many([x for v in lists for x in v]))
+    each = sum(int(ex.index.host_counts(ex._hashed(v)).sum()) for v in lists)
     assert cap.attrs["matched"] == int(ex.index.host_counts(union).sum())
     assert cap.attrs["matched"] < each
+    sets = {frozenset(s) for s in holders(ex, lists).values()}
+    assert probe.attrs["sets"] == len(sets)
+    assert probe.attrs["expanded"] == 0
+
+
+N_PAIRED = 16
+
+
+def paired_lists():
+    """16 seekers whose values make more holder sets than a launch of 16
+    holds (4 x 16 = 64): one value per pair of seekers (120 sets), the hot
+    value held by all, and one value of each seeker's own."""
+    pairs = [(a, b) for a in range(N_PAIRED) for b in range(a + 1, N_PAIRED)]
+    keys = [c for t in LAKE.tables for c in t.columns[0] if c != HOT]
+    pair_vals = [f"v{i}" for i in range(40)] + keys[:len(pairs) - 40]
+    own = keys[len(pairs) - 40:len(pairs) - 40 + N_PAIRED]
+    return [[HOT, own[i]] + [v for (a, b), v in zip(pairs, pair_vals)
+                             if i in (a, b)] for i in range(N_PAIRED)]
+
+
+@pytest.mark.parametrize("backend,shards", [("sorted", 1), ("bucket", 1),
+                                            ("sorted", 2)])
+@pytest.mark.parametrize("kind", ["SC", "KW"])
+def test_holder_sets_past_the_launch_are_walked_once_per_holder(
+        kind, backend, shards):
+    """More distinct holder sets than the launch holds: the sets whose
+    values would cost the fewest extra slots are expanded, each of their
+    values walked once per holding seeker.  Every seeker's ids and float32
+    scores still equal the oracle's (1 and 2 shards bit for bit), the
+    launch counts 64 sets, and ``capacity.matched`` counts every copy."""
+    make, oracle = {"SC": (blend.sc, oracle_sc), "KW": (blend.kw, oracle_kw)}[
+        kind]
+    lists = paired_lists()
+    exprs = [make(v, k=K) for v in lists]
+    session = blend.connect(LAKE, live=True, backend=backend,
+                            interpret=True) if shards == 1 else \
+        blend.connect(LAKE, shards=shards)
+    rec = otrace.Recorder()
+    with otrace.recording(rec):
+        resps = serve(session, exprs)
+    for v, resp in zip(lists, resps):
+        assert_oracle(resp, oracle(LAKE, v), LAKE.n_tables)
+    if shards > 1:
+        one = serve(blend.connect(LAKE, live=True), exprs)
+        for a, b in zip(one, resps):
+            assert a.table_ids == b.table_ids
+            assert np.array_equal(np.asarray(a.scores), np.asarray(b.scores))
+    cap, probe = launch_spans(rec, kind)
+    ex = blend.connect(LAKE, live=True).executor
+    held = holders(ex, lists)
+    hashes = np.array(list(held), np.uint32)
+    counts = dict(zip(held, ex.index.host_counts(hashes)))
+    # one value per multi-holder set: its extra slots if walked per holder
+    extra = sorted((len(s) - 1) * int(counts[h]) for h, s in held.items()
+                   if len(s) > 1)
+    n_fit = 4 * N_PAIRED - N_PAIRED          # room left for the singletons
+    assert len(extra) > n_fit
+    assert probe.attrs["sets"] == 4 * N_PAIRED
+    assert probe.attrs["expanded"] == len(extra) - n_fit
+    assert cap.attrs["matched"] == int(sum(counts.values())) + \
+        sum(extra[:len(extra) - n_fit])
 
 
 @pytest.mark.parametrize("sync", [True, False])
@@ -312,3 +392,38 @@ def test_capacity_and_probe_spans_count_slots_and_matches():
     assert cap.attrs["matched"] == int(store.host_counts(h).sum())
     chunk, n_chunks = fused._walk(cap.attrs["matched"])
     assert cap.attrs["slots"] == chunk * n_chunks
+
+
+def test_holder_sets_add_no_program(monkeypatch):
+    """SC batches of 1, 3, 8 and 16 seekers, and 16 whose holder sets do
+    not all fit: ``SC_seg`` traces once per distinct (chunk, width, padded
+    seekers) it meets, and the holder-set operands take their shapes from
+    those alone (``[width]`` ids, ``[4 * nsp, nsp]`` sets)."""
+    calls = []
+    real = seek.sc_seeker_seg
+
+    def recording(eng, q_hash, q_mask, set_id, sets, n_chunks, *, chunk,
+                  **kw):
+        calls.append((chunk, q_hash.shape, q_mask.shape, set_id.shape,
+                      sets.shape))
+        return real(eng, q_hash, q_mask, set_id, sets, n_chunks,
+                    chunk=chunk, **kw)
+
+    monkeypatch.setattr(seek, "sc_seeker_seg", recording)
+    jax.clear_caches()
+    before = seek.TRACE_COUNTS["SC_seg"]
+    session = blend.connect(LAKE, live=True)
+    lists = [[HOT, f"v{i % 7}", LAKE.tables[i % N_TABLES].columns[0][i + 1]]
+             for i in range(16)]
+    for n in (1, 3, 8, 16):
+        serve(session, [blend.sc(v, k=K) for v in lists[:n]])
+    rec = otrace.Recorder()
+    with otrace.recording(rec):
+        serve(session, [blend.sc(v, k=K) for v in paired_lists()])
+    assert launch_spans(rec, "SC")[1].attrs["expanded"] > 0
+    shapes = {(chunk, q[0], s[1]) for chunk, q, _, _, s in calls}
+    assert len(calls) == 5
+    assert seek.TRACE_COUNTS["SC_seg"] - before == len(shapes) == \
+        len(set(calls))
+    for _, q, m, ids, s in calls:
+        assert q == m == ids and s == (fused.SETS_PER_SEEKER * s[1], s[1])

@@ -14,7 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import seekers as seek
-from repro.core.fused import MAX_CHUNK
+from repro.core.fused import MAX_CHUNK, SETS_PER_SEEKER
 from repro.core.match import EngineConfig, MatchEngine
 from repro.dist.shard import GITTABLES_SCALE
 from repro.kernels.bucket_probe import ops as bucket_ops
@@ -118,9 +118,35 @@ def test_sc_seeker_fits_one_chip_at_gittables_quarter(sds):
     nq = 1024
     compiled = seek.sc_seeker_seg.lower(
         eng, sds((nq,), jnp.uint32), sds((nq,), jnp.bool_),
-        sds((nq, 4), jnp.bool_), sds((), jnp.int32),
+        sds((nq,), jnp.int32), sds((SETS_PER_SEEKER * 4, 4), jnp.bool_),
+        sds((), jnp.int32),
         chunk=MAX_CHUNK, n_tables=n_tables,
         max_cols=GITTABLES_SCALE["max_cols"]).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+
+
+def test_sc_seeker_fits_one_chip_at_webtables_zipf(sds):
+    """The fused SC program over the Zipf web-table lake with a batch's
+    2,048 distinct values and 16 seekers compiles and fits one chip's HBM:
+    its holder-set counts are [64 x 262,144] float32 (32,768 table slots
+    of 8 columns), and the product that turns them into seeker counts is
+    a dense dot."""
+    n, nnum = 1 << 21, 1 << 19
+    cfg = EngineConfig(backend="sorted", interpret=False, bucket_bits=12,
+                       bucket_widths=(), seg_bounds=((0, n, 1_880_885),),
+                       num_bounds=((0, nnum, 340_757),),
+                       n_tables=TABLE_SLOTS, max_cols=8, row_stride=256)
+    eng = engine_shapes(sds, n, nnum, cfg)
+    nq, nsp = 2048, 16
+    compiled = seek.sc_seeker_seg.lower(
+        eng, sds((nq,), jnp.uint32), sds((nq,), jnp.bool_),
+        sds((nq,), jnp.int32), sds((SETS_PER_SEEKER * nsp, nsp), jnp.bool_),
+        sds((), jnp.int32), chunk=MAX_CHUNK, n_tables=TABLE_SLOTS,
+        max_cols=8).compile()
+    assert "dot" in compiled.as_text() or "convolution" in compiled.as_text()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
@@ -168,8 +194,8 @@ def bucket_seeker_programs(sds):
     common = dict(chunk=MAX_CHUNK, n_seekers=2, n_tables=TABLE_SLOTS)
     return {
         "sc": (seek.sc_seeker_seg,
-               (eng, sds((nq,), u32), sds((nq,), b), sds((nq, 2), b),
-                sds((), i32)),
+               (eng, sds((nq,), u32), sds((nq,), b), sds((nq,), i32),
+                sds((SETS_PER_SEEKER * 2, 2), b), sds((), i32)),
                dict(chunk=MAX_CHUNK, n_tables=TABLE_SLOTS,
                     max_cols=MAX_COLS)),
         "mc": (seek.mc_seeker_seg,
